@@ -20,6 +20,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.core.hd.similarity import bitpack_bipolar
+
 
 @dataclasses.dataclass(frozen=True)
 class HDEncoderConfig:
@@ -86,6 +88,7 @@ def quantize_levels(values: jax.Array, num_levels: int) -> jax.Array:
     return jnp.where(present, lvl, 0)
 
 
+@jax.jit
 def encode_levels_batch(
     levels: jax.Array,
     id_hvs: jax.Array,
@@ -94,23 +97,30 @@ def encode_levels_batch(
     """Eq. 1 from *already quantized* levels. levels: (B, F) int in [0, m).
 
     Level 0 is the absent-peak sentinel and contributes nothing; sign ties
-    (acc == 0) resolve to -1. This is the levels-in entry point shared by
-    :func:`encode_batch_reference` and the serving raw-spectrum path
-    (``repro.serve.db_search.search_database_levels``), and the oracle the
-    fused encode->search kernel (``repro.kernels.encode_search``) must
-    match bit-exactly. Returns bipolar (B, D) int8 hypervectors.
+    (acc == 0) resolve to -1. Grouped by level, the sum is one
+    ``(B, F) @ (F, D)`` matmul per level,
+    ``acc = sum_l LV[l] * ((levels == l) @ ID)``, with bfloat16 operands
+    (0 and ±1 are exact) and float32 accumulation (exact for integer sums
+    below 2**24) — and its working set is ``(B, D)`` where the gather form
+    of :func:`encode_batch_reference` needs ``(B, F, D)``. This is the
+    levels-in entry point of the serving raw-spectrum path
+    (``repro.serve.db_search.search_database_levels``) and of the bank
+    encoder :func:`encode_bitpacked`, and the oracle the fused
+    encode->search kernel (``repro.kernels.encode_search``) must match
+    bit-exactly. Returns bipolar (B, D) int8 hypervectors.
     """
-    lv = level_hvs[levels]  # (B, F, D) int8
-    present = (levels > 0).astype(jnp.int32)  # level 0 = absent peak
-    acc = jnp.einsum(
-        "bf,bfd,fd->bd",
-        present,
-        lv.astype(jnp.int32),
-        id_hvs.astype(jnp.int32),
-        preferred_element_type=jnp.int32,
-    )
-    # sign with tie -> +1 (paper: sign outputs 1 when input positive else -1;
-    # zero maps to -1 there. We match the paper exactly.)
+    ids = id_hvs.astype(jnp.bfloat16)
+    lvs = level_hvs.astype(jnp.float32)
+
+    def add_level(lev, acc):
+        mask = (levels == lev).astype(jnp.bfloat16)
+        part = jax.lax.dot(mask, ids, preferred_element_type=jnp.float32)
+        return acc + part * lvs[lev]
+
+    acc = jax.lax.fori_loop(
+        1, level_hvs.shape[0], add_level,
+        jnp.zeros((levels.shape[0], id_hvs.shape[1]), jnp.float32))
+    # sign with the paper's convention: positive -> +1, zero or negative -> -1
     return jnp.where(acc > 0, jnp.int8(1), jnp.int8(-1))
 
 
@@ -121,52 +131,65 @@ def encode_batch_reference(
 ) -> jax.Array:
     """Pure-jnp oracle for Eq. 1. features: (B, F) float in [0,1].
 
-    Returns bipolar (B, D) int8 hypervectors.
+    Gathers every feature's level HV — the (B, F, D) form of the equation
+    — so it is for small batches only. Returns bipolar (B, D) int8
+    hypervectors.
     """
     levels = quantize_levels(features, level_hvs.shape[0])  # (B, F)
-    return encode_levels_batch(levels, id_hvs, level_hvs)
+    lv = level_hvs[levels]  # (B, F, D) int8
+    present = (levels > 0).astype(jnp.int32)  # level 0 = absent peak
+    acc = jnp.einsum(
+        "bf,bfd,fd->bd",
+        present,
+        lv.astype(jnp.int32),
+        id_hvs.astype(jnp.int32),
+        preferred_element_type=jnp.int32,
+    )
+    return jnp.where(acc > 0, jnp.int8(1), jnp.int8(-1))
 
 
-@partial(jax.jit, static_argnames=("block_features",))
+@jax.jit
 def encode_batch(
     features: jax.Array,
     id_hvs: jax.Array,
     level_hvs: jax.Array,
-    *,
-    block_features: int = 128,
 ) -> jax.Array:
-    """Memory-bounded ID-level encoder.
+    """Memory-bounded ID-level encoder: quantize, then
+    :func:`encode_levels_batch` — the math of :func:`encode_batch_reference`
+    without its (B, F, D) intermediate."""
+    levels = quantize_levels(features, level_hvs.shape[0])
+    return encode_levels_batch(levels, id_hvs, level_hvs)
 
-    Identical math to :func:`encode_batch_reference` but accumulates over
-    feature blocks with ``lax.scan`` so the (B, F, D) intermediate never
-    materializes — the same blocking the SpecPCM near-memory ASIC applies.
+
+@partial(jax.jit, static_argnames=("rows",))
+def _encode_pack_rows(levels, start, id_hvs, level_hvs, *, rows: int):
+    blk = jax.lax.dynamic_slice_in_dim(levels, start, rows)
+    return bitpack_bipolar(encode_levels_batch(blk, id_hvs, level_hvs))
+
+
+def encode_bitpacked(
+    levels: jax.Array,
+    id_hvs: jax.Array,
+    level_hvs: jax.Array,
+    *,
+    chunk_rows: int = 8192,
+) -> jax.Array:
+    """(N, F) levels -> (N, D/32) uint32 bit-packed Eq. 1 hypervectors.
+
+    Encodes ``chunk_rows`` rows at a time with one compiled program (the
+    chunk start is traced; a ragged last chunk is taken from the end and
+    trimmed): the unpacked (chunk, D) HVs are the largest intermediate, so
+    a library of any size encodes in bounded memory and only packed words
+    accumulate. Bit-identical to ``bitpack_bipolar(encode_batch_reference
+    (...))`` row for row.
     """
-    B, F = features.shape
-    num_levels, D = level_hvs.shape
-    if F % block_features != 0:
-        pad = block_features - F % block_features
-        # padded features encode level 0 with a zero ID so they are inert
-        features = jnp.pad(features, ((0, 0), (0, pad)))
-        id_hvs = jnp.pad(id_hvs, ((0, pad), (0, 0)))
-        F += pad
-    levels = quantize_levels(features, num_levels)  # (B, F)
-    nblk = F // block_features
-    levels_b = levels.reshape(B, nblk, block_features).transpose(1, 0, 2)
-    ids_b = id_hvs.reshape(nblk, block_features, D)
-
-    def step(acc, blk):
-        lvl, ids = blk
-        lv = level_hvs[lvl]  # (B, bf, D)
-        present = (lvl > 0).astype(jnp.int32)
-        acc = acc + jnp.einsum(
-            "bf,bfd,fd->bd",
-            present,
-            lv.astype(jnp.int32),
-            ids.astype(jnp.int32),
-            preferred_element_type=jnp.int32,
-        )
-        return acc, None
-
-    acc0 = jnp.zeros((B, D), jnp.int32)
-    acc, _ = jax.lax.scan(step, acc0, (levels_b, ids_b))
-    return jnp.where(acc > 0, jnp.int8(1), jnp.int8(-1))
+    n = int(levels.shape[0])
+    chunk = max(1, min(chunk_rows, n))
+    starts = list(range(0, n, chunk))
+    tail = n - starts[-1]
+    starts[-1] = n - chunk
+    out = [_encode_pack_rows(levels, s, id_hvs, level_hvs, rows=chunk)
+           for s in starts]
+    if tail < chunk:
+        out[-1] = out[-1][chunk - tail:]
+    return out[0] if len(out) == 1 else jnp.concatenate(out)

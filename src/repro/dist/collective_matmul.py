@@ -26,7 +26,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -44,9 +43,9 @@ def _ring_fn(mesh: Mesh, axis: str):
             acc = acc + chunk
         return acc
 
-    return jax.jit(shard_map(local, mesh=mesh,
-                             in_specs=(P(None, axis), P(axis, None)),
-                             out_specs=P(None, None), check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(None, axis), P(axis, None)),
+                                 out_specs=P(None, None), check_vma=False))
 
 
 def ring_matmul_reduce(x: jax.Array, w: jax.Array, mesh: Mesh,
@@ -82,9 +81,9 @@ def _ag_fn(mesh: Mesh, axis: str):
                 chunk = jax.lax.ppermute(chunk, axis, perm)
         return out
 
-    return jax.jit(shard_map(local, mesh=mesh,
-                             in_specs=(P(axis, None), P(None, axis)),
-                             out_specs=P(None, axis), check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(P(axis, None), P(None, axis)),
+                                 out_specs=P(None, axis), check_vma=False))
 
 
 def ag_matmul_pipelined(x: jax.Array, w: jax.Array, mesh: Mesh,

@@ -40,7 +40,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 DCN_METHODS = ("none", "int8", "topk", "topk_ef")
@@ -210,9 +209,9 @@ def dcn_allreduce_tree(grads_stacked, error, mesh: Mesh, axis: str = "pod",
         red = jax.tree.map(lambda x: jax.lax.psum(x, axis), sent)
         return red, jax.tree.map(lambda x: x[None], new_e)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(axis), P(axis), P()),
-                   out_specs=(P(), P(axis)), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(axis), P(axis), P()),
+                       out_specs=(P(), P(axis)), check_vma=False)
     return fn(grads_stacked, error, key)
 
 
@@ -232,8 +231,9 @@ def _allreduce_fn(mesh: Mesh, axis: str, method: str, topk_frac: float,
             xl = _topk(xl, topk_frac)
         return jax.lax.psum(xl, axis)
 
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=(spec, P(None)),
-                             out_specs=spec, check_rep=False))
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(spec, P(None)),
+                                 out_specs=spec, check_vma=False))
 
 
 def cross_pod_allreduce(x: jax.Array, mesh: Mesh, axis: str = "pod",

@@ -20,40 +20,61 @@ re-resolves on the next call and jit caches key on the concrete values.
 Alignment rationale (see the Pallas guide's tiling table): the last block
 dimension maps to the 128-wide lane axis and the second-to-last to 8
 sublanes (float32/int32 tiles), so Q-like / sublane-side blocks must be
-multiples of 8. R-like / lane-side blocks allow half-register 64s (the
-ops layers pad the array up to the block, and the established API accepts
-``block_r=64``); the full-tile dims (``block_d``, ``tile_cols``) that
-feed MXU-shaped loads stay multiples of 128. ``word_chunk`` slices the
-packed uint32 word axis inside the popcount loop and only needs to keep
-whole 4-word groups (a 128-bit load) per step.
+multiples of 8. R-like blocks sit on the sublane side of the bank tile
+and allow 64s (the ops layers pad the array up to the block). Every block
+or in-kernel slice on the lane axis must be a multiple of 128 or span the
+whole axis — Mosaic refuses a lane slice it cannot prove 128-aligned. So
+``block_d``, ``tile_cols`` and ``block_f`` (the feature axis of the level
+block) are multiples of 128, and ``word_chunk`` (the packed uint32 word
+slice of the popcount loop) is a multiple of 128 that the ops layers clamp
+to the whole word axis when the bank is narrower (D <= 4096).
 """
 
 from __future__ import annotations
 
+import jax
+
 # per-op alignment constraints: block name -> required multiple
 ALIGN: dict[str, dict[str, int]] = {
-    "topk_hamming": {"block_q": 8, "block_r": 64, "word_chunk": 4},
-    "topk_hamming_banded": {"block_q": 8, "block_r": 64, "word_chunk": 4},
-    "encode_search": {"block_q": 8, "block_r": 64, "block_f": 8,
-                      "word_chunk": 4},
-    "encode_search_banded": {"block_q": 8, "block_r": 64, "block_f": 8,
-                             "word_chunk": 4},
-    "hd_encode": {"block_b": 8, "block_d": 128, "block_f": 8},
+    "topk_hamming": {"block_q": 8, "block_r": 64, "word_chunk": 128},
+    "topk_hamming_banded": {"block_q": 8, "block_r": 64, "word_chunk": 128},
+    "encode_search": {"block_q": 8, "block_r": 64, "block_f": 128,
+                      "word_chunk": 128},
+    "encode_search_banded": {"block_q": 8, "block_r": 64, "block_f": 128,
+                             "word_chunk": 128},
+    "hd_encode": {"block_b": 8, "block_d": 128, "block_f": 128},
     "imc_mvm": {"block_q": 8, "block_r": 64, "tile_cols": 128},
 }
 
 # the pre-autotuner hand-picked blocks — the fallback when no table entry
 # exists, and the baseline every sweep candidate must beat to displace
 DEFAULTS: dict[str, dict[str, int]] = {
-    "topk_hamming": {"block_q": 128, "block_r": 128, "word_chunk": 32},
-    "topk_hamming_banded": {"block_q": 128, "block_r": 128, "word_chunk": 32},
+    "topk_hamming": {"block_q": 128, "block_r": 128, "word_chunk": 128},
+    "topk_hamming_banded": {"block_q": 128, "block_r": 128,
+                            "word_chunk": 128},
     "encode_search": {"block_q": 8, "block_r": 128, "block_f": 128,
-                      "word_chunk": 32},
+                      "word_chunk": 128},
     "encode_search_banded": {"block_q": 8, "block_r": 128, "block_f": 128,
-                             "word_chunk": 32},
+                             "word_chunk": 128},
     "hd_encode": {"block_b": 8, "block_d": 256, "block_f": 128},
     "imc_mvm": {"block_q": 128, "block_r": 128, "tile_cols": 128},
 }
+
+
+def default_interpret() -> bool:
+    """Pallas interpret mode everywhere except on a TPU backend."""
+    return jax.default_backend() != "tpu"
+
+
+def round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def word_padding(n_words: int, word_chunk: int) -> int:
+    """Zero words to append so the popcount loop's lane slices are legal:
+    none when the whole word axis fits one chunk (the slice then spans the
+    axis), else up to a ``word_chunk`` multiple."""
+    return 0 if n_words <= word_chunk else (-n_words) % word_chunk
 
 
 def validate_block(op: str, name: str, value) -> int:

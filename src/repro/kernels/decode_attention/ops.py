@@ -7,11 +7,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.block_utils import default_interpret
 from repro.kernels.decode_attention.decode_attention import decode_attention_pallas_call
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -27,7 +24,7 @@ def decode_attention_pallas(
     interpret: bool | None = None,
 ) -> jax.Array:
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     s = k8.shape[1]
     chunk = min(chunk, s)
     if s % chunk:

@@ -6,14 +6,16 @@ Padding conventions (all inert by construction, proven by
   * **features** pad to a ``block_f`` multiple with level 0 (absent peak)
     and zero ID rows — zero contribution to the accumulator;
   * **HD dims** pad to the bank's storage width (a ``word_chunk``-word
-    multiple when packed, a 128-lane multiple for int8) with zero
+    multiple when packed and wider than one chunk, a 128-lane multiple
+    for int8) with zero
     codebook columns: the accumulator is 0 there, so queries encode the
     pad dims to sign(0) = -1 -> packed bit 0, while padded reference
     words/columns are zero — XOR popcount and int8 dot cross terms both
     vanish, leaving scores on the true ``dim`` scale;
   * **query rows** pad with all-zero spectra and are sliced off;
-  * **reference rows** pad with zeros and mask to the sentinel via
-    ``num_valid``.
+  * **reference rows** pad only up to one R tile — padding a large bank
+    would copy it on every call: the ragged last tile reads past the end,
+    and those columns mask to the sentinel via ``num_valid``.
 """
 
 from __future__ import annotations
@@ -23,20 +25,17 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.block_utils import resolve_blocks
+from repro.kernels.block_utils import (
+    default_interpret,
+    resolve_blocks,
+    round_up,
+    word_padding,
+)
 from repro.kernels.encode_search.encode_search import (
     encode_search_banded_pallas_call,
     encode_search_pallas_call,
 )
 from repro.kernels.topk_hamming.ops import canonicalize_overflow_slots
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _round_up(n: int, m: int) -> int:
-    return -(-n // m) * m
 
 
 def _check_operands(levels, id_hvs, level_hvs, r, k):
@@ -71,16 +70,17 @@ def _pad_operands(levels, id_hvs, level_hvs, r, *, packed: bool, bq: int,
     Q, F = levels.shape
     D = id_hvs.shape[1]
     R, W = r.shape
-    pq, pf, pr = (-Q) % bq, (-F) % block_f, (-R) % br
-    pw = ((-W) % word_chunk) if packed else ((-D) % 128)
+    pq, pf, pr = (-Q) % bq, (-F) % block_f, max(br - R, 0)
+    pw = word_padding(W, word_chunk) if packed else (-D) % 128
     pd = 32 * pw if packed else pw
     if pq or pf:
         levels = jnp.pad(levels, ((0, pq), (0, pf)))
     if pf or pd:
         id_hvs = jnp.pad(id_hvs, ((0, pf), (0, pd)))
+    level_hvs = level_hvs.astype(jnp.float32)
     if pd:
         level_hvs = jnp.pad(level_hvs, ((0, 0), (0, pd)))
-    if pr or pw:
+    if pr or pw:  # rows pad only up to one tile; see module docstring
         r = jnp.pad(r, ((0, pr), (0, pw)))
     return levels, id_hvs, level_hvs, r
 
@@ -141,13 +141,13 @@ def _encode_search_jit(
     interpret: bool | None,
 ) -> tuple[jax.Array, jax.Array]:
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     packed = _check_operands(levels, id_hvs, level_hvs, r, k)
     Q, _ = levels.shape
     R = r.shape[0]
-    bq = min(block_q, _round_up(Q, 8))
-    br = min(block_r, _round_up(R, 128))
-    bf = min(block_f, _round_up(levels.shape[1], 8))
+    bq = min(block_q, round_up(Q, 8))
+    br = min(block_r, round_up(R, 128))
+    bf = min(block_f, round_up(levels.shape[1], 8))
     levels, id_hvs, level_hvs, r = _pad_operands(
         levels.astype(jnp.int32), id_hvs, level_hvs, r, packed=packed,
         bq=bq, br=br, block_f=bf, word_chunk=word_chunk)
@@ -223,17 +223,17 @@ def _encode_search_banded_jit(
     canonicalize: bool,
 ) -> tuple[jax.Array, jax.Array]:
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     packed = _check_operands(levels, id_hvs, level_hvs, r, k)
     Q, _ = levels.shape
     R = r.shape[0]
     if starts.shape != (Q,) or lens.shape != (Q,):
         raise ValueError(
             f"starts/lens must be ({Q},), got {starts.shape}/{lens.shape}")
-    bq = min(block_q, _round_up(Q, 8))
-    br = min(block_r, _round_up(R, 128))
-    bf = min(block_f, _round_up(levels.shape[1], 8))
-    pq, pr = (-Q) % bq, (-R) % br
+    bq = min(block_q, round_up(Q, 8))
+    br = min(block_r, round_up(R, 128))
+    bf = min(block_f, round_up(levels.shape[1], 8))
+    pq = (-Q) % bq
     levels, id_hvs, level_hvs, r = _pad_operands(
         levels.astype(jnp.int32), id_hvs, level_hvs, r, packed=packed,
         bq=bq, br=br, block_f=bf, word_chunk=word_chunk)
@@ -248,7 +248,7 @@ def _encode_search_banded_jit(
         s = jnp.pad(s, (0, pq), mode="edge")
         e = jnp.pad(e, (0, pq), mode="edge")
 
-    total_tiles = (R + pr) // br
+    total_tiles = -(-R // br)
     nt = total_tiles if num_tiles is None else min(num_tiles, total_tiles)
     tb = jnp.min(s.reshape(-1, bq) // br, axis=1)
     tb = jnp.clip(tb, 0, total_tiles - nt).astype(jnp.int32)

@@ -7,11 +7,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.block_utils import default_interpret, word_padding
 from repro.kernels.hamming_pop.hamming_pop import hamming_pop_pallas_call
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("dim", "block_q", "block_r", "word_chunk",
@@ -23,7 +20,7 @@ def hamming_pop_pallas(
     dim: int,
     block_q: int = 128,
     block_r: int = 128,
-    word_chunk: int = 32,
+    word_chunk: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
     """(Q, W) x (R, W) packed uint32 -> (Q, R) int32 hamming similarity.
@@ -33,10 +30,11 @@ def hamming_pop_pallas(
     zeros (XOR -> 0 -> popcount 0) so similarities are unaffected.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     Q, W = q_packed.shape
     R = r_packed.shape[0]
-    pq, pr, pw = (-Q) % block_q, (-R) % block_r, (-W) % word_chunk
+    pq, pr = (-Q) % block_q, (-R) % block_r
+    pw = word_padding(W, word_chunk)
     if pq or pw:
         q_packed = jnp.pad(q_packed, ((0, pq), (0, pw)))
     if pr or pw:

@@ -7,12 +7,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.block_utils import resolve_blocks
+from repro.kernels.block_utils import (
+    default_interpret,
+    resolve_blocks,
+    round_up,
+)
 from repro.kernels.hd_encode.hd_encode import hd_encode_pallas_call
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def hd_encode_pallas(
@@ -53,9 +53,10 @@ def _hd_encode_jit(
     interpret: bool | None,
 ) -> jax.Array:
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     B, F = levels.shape
     m, D = level_hvs.shape
+    block_f = min(block_f, round_up(F, 8))
     pb, pf, pd = (-B) % block_b, (-F) % block_f, (-D) % block_d
     if pb or pf:
         levels = jnp.pad(levels, ((0, pb), (0, pf)))
@@ -64,7 +65,7 @@ def _hd_encode_jit(
     if pd:
         level_hvs = jnp.pad(level_hvs, ((0, 0), (0, pd)))
     out = hd_encode_pallas_call(
-        levels.astype(jnp.int32), id_hvs, level_hvs,
+        levels.astype(jnp.int32), id_hvs, level_hvs.astype(jnp.float32),
         block_b=block_b, block_d=block_d, block_f=block_f,
         interpret=interpret,
     )

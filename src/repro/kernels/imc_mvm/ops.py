@@ -10,12 +10,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.block_utils import resolve_blocks
+from repro.kernels.block_utils import default_interpret, resolve_blocks
 from repro.kernels.imc_mvm.imc_mvm import imc_mvm_pallas_call
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def imc_mvm_pallas(
@@ -66,7 +62,7 @@ def _imc_mvm_jit(
     interpret: bool | None,
 ) -> jax.Array:
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     q = queries.astype(jnp.float32)
     w = weights.astype(jnp.float32)
     Q, Dp = q.shape

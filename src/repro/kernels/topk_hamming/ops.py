@@ -7,7 +7,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.block_utils import resolve_blocks
+from repro.kernels.block_utils import (
+    default_interpret,
+    resolve_blocks,
+    round_up,
+    word_padding,
+)
 from repro.kernels.topk_hamming.topk_hamming import (
     topk_hamming_banded_pallas_call,
     topk_hamming_pallas_call,
@@ -16,12 +21,35 @@ from repro.kernels.topk_hamming.topk_hamming import (
 _SENTINEL = jnp.iinfo(jnp.int32).min
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _prepare(q, r, k: int, block_q: int, block_r: int, word_chunk: int):
+    """Check the operands and pad them for the kernels; returns
+    ``(q, r, block_q, block_r)`` with the blocks shrunk to the problem."""
+    if q.ndim != 2 or r.ndim != 2 or q.shape[1] != r.shape[1]:
+        raise ValueError(f"bad operand shapes {q.shape} x {r.shape}")
+    if q.dtype != r.dtype:
+        raise ValueError(f"dtype mismatch {q.dtype} vs {r.dtype}")
+    packed = q.dtype == jnp.uint32
+    if not packed and q.dtype != jnp.int8:
+        raise ValueError(f"expected uint32 (packed) or int8, got {q.dtype}")
+    Q, W = q.shape
+    R = r.shape[0]
+    if not 1 <= k <= R:
+        raise ValueError(f"k={k} must be in [1, {R}]")
 
-
-def _round_up(n: int, m: int) -> int:
-    return -(-n // m) * m
+    # shrink blocks to the (aligned) problem so tiny searches don't pay
+    # full 128x128 tiles in interpret mode
+    bq = min(block_q, round_up(Q, 8))
+    br = min(block_r, round_up(R, 128))
+    # bank rows are padded only up to one tile — a padded copy of a large
+    # bank on every call is what the ragged last tile avoids: it reads
+    # past the end, and its columns mask off
+    pq, pr = (-Q) % bq, max(br - R, 0)
+    pw = word_padding(W, word_chunk) if packed else (-W) % 128
+    if pq or pw:
+        q = jnp.pad(q, ((0, pq), (0, pw)))
+    if pr or pw:
+        r = jnp.pad(r, ((0, pr), (0, pw)))
+    return q, r, bq, br
 
 
 def topk_hamming_pallas(
@@ -54,8 +82,9 @@ def topk_hamming_pallas(
       this (device kind, shape bucket), else the 128x128 defaults — see
       :mod:`repro.kernels.block_utils`.
 
-    Zero row/word padding is harmless: padded reference rows fall outside
-    ``num_valid`` and padded words XOR to zero on both sides.
+    Padding is harmless: bank rows past the end (up to one tile, or read
+    past the end by a ragged last tile) fall outside ``num_valid``, and
+    padded words XOR to zero on both sides.
     """
     cfg = resolve_blocks(
         "topk_hamming", (q.shape[0], r.shape[0], q.shape[1]),
@@ -81,29 +110,9 @@ def _topk_hamming_jit(
     interpret: bool | None,
 ) -> tuple[jax.Array, jax.Array]:
     if interpret is None:
-        interpret = _default_interpret()
-    if q.ndim != 2 or r.ndim != 2 or q.shape[1] != r.shape[1]:
-        raise ValueError(f"bad operand shapes {q.shape} x {r.shape}")
-    if q.dtype != r.dtype:
-        raise ValueError(f"dtype mismatch {q.dtype} vs {r.dtype}")
-    packed = q.dtype == jnp.uint32
-    if not packed and q.dtype != jnp.int8:
-        raise ValueError(f"expected uint32 (packed) or int8, got {q.dtype}")
-    Q, W = q.shape
-    R = r.shape[0]
-    if not 1 <= k <= R:
-        raise ValueError(f"k={k} must be in [1, {R}]")
-
-    # shrink blocks to the (aligned) problem so tiny searches don't pay
-    # full 128x128 tiles in interpret mode
-    bq = min(block_q, _round_up(Q, 8))
-    br = min(block_r, _round_up(R, 128))
-    lane = word_chunk if packed else 128
-    pq, pr, pw = (-Q) % bq, (-R) % br, (-W) % lane
-    if pq or pw:
-        q = jnp.pad(q, ((0, pq), (0, pw)))
-    if pr or pw:
-        r = jnp.pad(r, ((0, pr), (0, pw)))
+        interpret = default_interpret()
+    Q, R = q.shape[0], r.shape[0]
+    q, r, bq, br = _prepare(q, r, k, block_q, block_r, word_chunk)
 
     nv = R if num_valid is None else num_valid
     nv = jnp.minimum(jnp.asarray(nv, jnp.int32).reshape(1), R)
@@ -223,30 +232,13 @@ def _topk_hamming_banded_jit(
     canonicalize: bool,
 ) -> tuple[jax.Array, jax.Array]:
     if interpret is None:
-        interpret = _default_interpret()
-    if q.ndim != 2 or r.ndim != 2 or q.shape[1] != r.shape[1]:
-        raise ValueError(f"bad operand shapes {q.shape} x {r.shape}")
-    if q.dtype != r.dtype:
-        raise ValueError(f"dtype mismatch {q.dtype} vs {r.dtype}")
-    packed = q.dtype == jnp.uint32
-    if not packed and q.dtype != jnp.int8:
-        raise ValueError(f"expected uint32 (packed) or int8, got {q.dtype}")
-    Q, W = q.shape
-    R = r.shape[0]
-    if not 1 <= k <= R:
-        raise ValueError(f"k={k} must be in [1, {R}]")
+        interpret = default_interpret()
+    Q, R = q.shape[0], r.shape[0]
+    q, r, bq, br = _prepare(q, r, k, block_q, block_r, word_chunk)
     if starts.shape != (Q,) or lens.shape != (Q,):
         raise ValueError(
             f"starts/lens must be ({Q},), got {starts.shape}/{lens.shape}")
-
-    bq = min(block_q, _round_up(Q, 8))
-    br = min(block_r, _round_up(R, 128))
-    lane = word_chunk if packed else 128
-    pq, pr, pw = (-Q) % bq, (-R) % br, (-W) % lane
-    if pq or pw:
-        q = jnp.pad(q, ((0, pq), (0, pw)))
-    if pr or pw:
-        r = jnp.pad(r, ((0, pr), (0, pw)))
+    pq = q.shape[0] - Q
 
     nv = R if num_valid is None else num_valid
     nv = jnp.minimum(jnp.asarray(nv, jnp.int32), R)
@@ -258,7 +250,7 @@ def _topk_hamming_banded_jit(
         s = jnp.pad(s, (0, pq), mode="edge")
         e = jnp.pad(e, (0, pq), mode="edge")
 
-    total_tiles = (R + pr) // br
+    total_tiles = -(-R // br)
     nt = total_tiles if num_tiles is None else min(num_tiles, total_tiles)
     tb = jnp.min(s.reshape(-1, bq) // br, axis=1)
     tb = jnp.clip(tb, 0, total_tiles - nt).astype(jnp.int32)

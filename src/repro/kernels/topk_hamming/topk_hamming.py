@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.hamming_pop.hamming_pop import xor_popcount
+
 _SENTINEL = jnp.iinfo(jnp.int32).min
 _BIG = jnp.iinfo(jnp.int32).max
 
@@ -58,32 +60,21 @@ def _select_topk(vals: jax.Array, idx: jax.Array, k: int
     return jnp.concatenate(out_v, axis=1), jnp.concatenate(out_i, axis=1)
 
 
-def _tile_scores(q_ref, r_ref, *, dim: int, word_chunk: int, packed: bool
-                 ) -> jax.Array:
+def _tile_scores(q_ref, r_ref, pc_ref, *, dim: int, word_chunk: int,
+                 packed: bool) -> jax.Array:
     """(bq, br) int32 similarity tile: XOR+popcount on the bipolar dot scale
-    for packed uint32 inputs, a plain integer dot for int8."""
-    bq = q_ref.shape[0]
-    br = r_ref.shape[0]
+    for packed uint32 inputs (through the (bq, br) int32 VMEM scratch
+    ``pc_ref``), a plain integer dot for int8."""
     if packed:
-        n_words = q_ref.shape[1]
-
-        def body(c, acc):
-            w0 = c * word_chunk
-            qc = q_ref[:, pl.dslice(w0, word_chunk)]   # (bq, wc) uint32
-            rc = r_ref[:, pl.dslice(w0, word_chunk)]   # (br, wc)
-            x = qc[:, None, :] ^ rc[None, :, :]        # (bq, br, wc)
-            return acc + jax.lax.population_count(x).astype(jnp.int32).sum(-1)
-
-        acc = jax.lax.fori_loop(0, n_words // word_chunk, body,
-                                jnp.zeros((bq, br), jnp.int32))
-        return dim - 2 * acc  # <q, r> for bipolar HVs, exactly
+        xor_popcount(q_ref, r_ref, pc_ref, word_chunk=word_chunk)
+        return dim - 2 * pc_ref[...]  # <q, r> for bipolar HVs, exactly
     return jax.lax.dot_general(
         q_ref[...], r_ref[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32)
 
 
 def _topk_kernel(nv_ref, q_ref, r_ref, ovals_ref, oidx_ref,
-                 svals_ref, sidx_ref, *, dim: int, k: int, block_r: int,
+                 svals_ref, sidx_ref, pc_ref, *, dim: int, k: int, block_r: int,
                  word_chunk: int, packed: bool, r_padded: int):
     j = pl.program_id(1)
     bq = q_ref.shape[0]
@@ -98,8 +89,8 @@ def _topk_kernel(nv_ref, q_ref, r_ref, ovals_ref, oidx_ref,
         sidx_ref[...] = r_padded + jax.lax.broadcasted_iota(
             jnp.int32, (bq, k), 1)
 
-    scores = _tile_scores(q_ref, r_ref, dim=dim, word_chunk=word_chunk,
-                          packed=packed)
+    scores = _tile_scores(q_ref, r_ref, pc_ref, dim=dim,
+                          word_chunk=word_chunk, packed=packed)
 
     col = j * block_r + jax.lax.broadcasted_iota(jnp.int32, (bq, br), 1)
     scores = jnp.where(col < nv_ref[0], scores, _SENTINEL)
@@ -124,7 +115,7 @@ def topk_hamming_pallas_call(
     k: int,
     block_q: int = 128,
     block_r: int = 128,
-    word_chunk: int = 32,
+    word_chunk: int = 128,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (vals (Q, k), idx (Q, k)) — the streaming top-k, never
@@ -132,15 +123,16 @@ def topk_hamming_pallas_call(
     Q, W = q.shape
     R = r.shape[0]
     packed = q.dtype == jnp.uint32
-    assert Q % block_q == 0 and R % block_r == 0
-    assert not packed or W % word_chunk == 0
+    assert Q % block_q == 0
+    assert not packed or W % min(word_chunk, W) == 0
+    n_r = pl.cdiv(R, block_r)  # a ragged last tile masks via num_valid
 
     kernel = functools.partial(
         _topk_kernel, dim=dim, k=k, block_r=block_r, word_chunk=word_chunk,
-        packed=packed, r_padded=R)
+        packed=packed, r_padded=n_r * block_r)
     return pl.pallas_call(
         kernel,
-        grid=(Q // block_q, R // block_r),
+        grid=(Q // block_q, n_r),
         in_specs=[
             pl.BlockSpec((1,), lambda i, j: (0,), memory_space=pltpu.SMEM),
             pl.BlockSpec((block_q, W), lambda i, j: (i, 0)),
@@ -157,13 +149,14 @@ def topk_hamming_pallas_call(
         scratch_shapes=[
             pltpu.VMEM((block_q, k), jnp.int32),
             pltpu.VMEM((block_q, k), jnp.int32),
+            pltpu.VMEM((block_q, block_r), jnp.int32),
         ],
         interpret=interpret,
     )(num_valid, q, r)
 
 
 def _topk_banded_kernel(tb_ref, q_ref, r_ref, starts_ref, ends_ref,
-                        ovals_ref, oidx_ref, svals_ref, sidx_ref, *,
+                        ovals_ref, oidx_ref, svals_ref, sidx_ref, pc_ref, *,
                         dim: int, k: int, block_r: int, word_chunk: int,
                         packed: bool, r_padded: int):
     """Banded variant: only ``num_tiles`` R tiles per Q block are visited,
@@ -188,8 +181,8 @@ def _topk_banded_kernel(tb_ref, q_ref, r_ref, starts_ref, ends_ref,
         sidx_ref[...] = r_padded + jax.lax.broadcasted_iota(
             jnp.int32, (bq, k), 1)
 
-    scores = _tile_scores(q_ref, r_ref, dim=dim, word_chunk=word_chunk,
-                          packed=packed)
+    scores = _tile_scores(q_ref, r_ref, pc_ref, dim=dim,
+                          word_chunk=word_chunk, packed=packed)
 
     tile = tb_ref[i] + j
     col = tile * block_r + jax.lax.broadcasted_iota(jnp.int32, (bq, br), 1)
@@ -219,7 +212,7 @@ def topk_hamming_banded_pallas_call(
     num_tiles: int,
     block_q: int = 128,
     block_r: int = 128,
-    word_chunk: int = 32,
+    word_chunk: int = 128,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Banded streaming top-k: grid (Q blocks, num_tiles), scanning only
@@ -228,19 +221,20 @@ def topk_hamming_banded_pallas_call(
     Caller contract: for every Q block i, every query's ``[start, end)``
     must lie inside the scanned rows
     ``[tile_base[i] * block_r, (tile_base[i] + num_tiles) * block_r)``
-    and ``tile_base[i] + num_tiles <= R // block_r`` — band rows outside
+    and ``tile_base[i] + num_tiles <= cdiv(R, block_r)`` — band rows outside
     the scanned window would be silently skipped.
     """
     Q, W = q.shape
     R = r.shape[0]
     packed = q.dtype == jnp.uint32
-    assert Q % block_q == 0 and R % block_r == 0
-    assert not packed or W % word_chunk == 0
-    assert 1 <= num_tiles <= R // block_r
+    assert Q % block_q == 0
+    assert not packed or W % min(word_chunk, W) == 0
+    n_r = pl.cdiv(R, block_r)
+    assert 1 <= num_tiles <= n_r
 
     kernel = functools.partial(
         _topk_banded_kernel, dim=dim, k=k, block_r=block_r,
-        word_chunk=word_chunk, packed=packed, r_padded=R)
+        word_chunk=word_chunk, packed=packed, r_padded=n_r * block_r)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(Q // block_q, num_tiles),
@@ -257,6 +251,7 @@ def topk_hamming_banded_pallas_call(
         scratch_shapes=[
             pltpu.VMEM((block_q, k), jnp.int32),
             pltpu.VMEM((block_q, k), jnp.int32),
+            pltpu.VMEM((block_q, block_r), jnp.int32),
         ],
     )
     return pl.pallas_call(

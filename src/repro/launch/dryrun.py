@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, SHAPES, get_config
 from repro.configs.shapes import applicable
 from repro.dist.sharding import logical_to_sharding, set_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import (
     active_profile,
@@ -243,6 +244,7 @@ def main():
                     help="sharding rule preset (default | fsdp_only)")
     ap.add_argument("--tag", default="", help="artifact filename suffix")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.dist.sharding import RULE_PRESETS
     rules = RULE_PRESETS[args.rules]

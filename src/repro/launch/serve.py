@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.data.tokens import TokenPipeline
 from repro.dist.sharding import set_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.models.model_zoo import build_model
 from repro.train.serve_step import make_decode_step, make_prefill
@@ -30,6 +31,7 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -28,6 +28,7 @@ from repro.core.hd.clustering import (
     incorrect_clustering_ratio,
 )
 from repro.dist.sharding import set_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.serve import BankRegistry, ClusteringConfig, DBSearchServer
 from repro.spectra import SyntheticMSConfig, generate_dataset
@@ -60,6 +61,7 @@ def main(argv=None):
                     help="continuous-batching mode (shared scheduler slots)")
     ap.add_argument("--num-slots", type=int, default=2)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.tenants < 1:
         raise SystemExit("--tenants must be >= 1")

@@ -28,6 +28,7 @@ import numpy as np
 from repro.core import SpecPCMConfig, encode_and_pack
 from repro.core.hd.encoding import quantize_levels
 from repro.dist.sharding import set_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.serve import (
     BankRegistry,
@@ -117,6 +118,7 @@ def main(argv=None):
                          "the delta exceeds this fraction of total rows "
                          "(default: never compact)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.tenants < 1:
         raise SystemExit("--tenants must be >= 1")

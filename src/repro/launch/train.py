@@ -23,6 +23,7 @@ from repro.data.tokens import TokenPipeline
 from repro.dist.checkpoint import CheckpointManager
 from repro.dist.sharding import is_axes_leaf, logical_to_sharding, set_mesh
 from repro.dist.straggler import Action, StragglerMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models.model_zoo import build_model
 from repro.train.optimizer import AdamWConfig
@@ -67,6 +68,7 @@ def main(argv=None):
     ap.add_argument("--mesh", default="debug",
                     choices=["debug", "single", "multi"])
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

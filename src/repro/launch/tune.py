@@ -22,6 +22,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.tune import ENV_VAR
 from repro.tune.sweep import OPS, build_tuning_table, tuned_vs_default_ratio
 
@@ -39,6 +40,7 @@ def main(argv=None):
     ap.add_argument("--skip-ceilings", action="store_true",
                     help="sweep blocks only; keep the table ceiling-free")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ops = None
     if args.ops:

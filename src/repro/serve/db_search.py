@@ -68,7 +68,6 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.hd.encoding import (
@@ -290,13 +289,18 @@ def shard_database(refs: jax.Array, *, decoys: jax.Array | None = None,
                    decoy_precursor: np.ndarray | None = None,
                    block_q: int | None = None,
                    block_r: int | None = None,
-                   word_chunk: int | None = None
+                   word_chunk: int | None = None,
+                   dim: int | None = None
                    ) -> ShardedDatabase:
-    """Build a :class:`ShardedDatabase` from bipolar (R, D) reference HVs.
+    """Build a :class:`ShardedDatabase` from bipolar (R, D) reference HVs,
+    or from already bit-packed (R, D/32) uint32 words with ``dim=D``.
 
-    decoys: optional (Rd, D) decoy HVs, stored *before* the targets (see
-      module docstring for why the order matters).
-    pack: True / False / "auto" (bit-pack whenever D % 32 == 0).
+    decoys: optional decoy HVs in the same form as ``refs``, stored
+      *before* the targets (see module docstring for why the order
+      matters).
+    pack: True / False / "auto" (bit-pack whenever D % 32 == 0); packed
+      inputs stay packed. Each block is brought to its storage form before
+      the blocks are joined, so an unpacked bank never exists whole.
     emulate_shards: with no mesh, pad/slice the bank as if it were split
       into this many shards and run the identical local-top-k/merge
       pipeline shard-by-shard on one device — the tier-1 stand-in for the
@@ -318,6 +322,8 @@ def shard_database(refs: jax.Array, *, decoys: jax.Array | None = None,
       trace time (:mod:`repro.kernels.block_utils`). The OMS banded
       routes keep their fixed ``block_q``/``block_r`` (the host-side tile
       budget is priced in those units) regardless of these overrides.
+    dim: the HD dimensionality of packed uint32 inputs (required for them,
+      ignored otherwise).
     The padded bank is device_put row-sharded over ``axis`` when a mesh
     with that axis (size > 1) is supplied; otherwise it stays local.
     """
@@ -325,15 +331,41 @@ def shard_database(refs: jax.Array, *, decoys: jax.Array | None = None,
                         ("word_chunk", word_chunk)):
         if _val is not None:
             validate_block("topk_hamming", _name, _val)
-    dim = int(refs.shape[-1])
+    prepacked = refs.dtype == jnp.uint32
+    if prepacked:
+        if dim is None or dim != 32 * int(refs.shape[-1]):
+            raise ValueError(f"packed refs of {refs.shape[-1]} words need "
+                             f"dim={32 * int(refs.shape[-1])}, got {dim}")
+        if pack is False:
+            raise ValueError("pack=False cannot take packed uint32 refs")
+        packed = True
+    else:
+        dim = int(refs.shape[-1])
+        if pack == "auto":
+            packed = dim % 32 == 0
+        else:
+            packed = bool(pack)
+            if packed and dim % 32 != 0:
+                raise ValueError(
+                    f"pack=True requires D % 32 == 0, got D={dim}")
+
+    def stored(x):
+        if prepacked:
+            return x
+        return bitpack_bipolar(x) if packed else x.astype(jnp.int8)
+
     num_decoys = 0
-    bank = refs
+    blocks = [stored(refs)]
     if decoys is not None:
-        if decoys.shape[-1] != dim:
-            raise ValueError(f"decoy dim {decoys.shape[-1]} != ref dim {dim}")
+        if ((decoys.dtype == jnp.uint32) != prepacked
+                or decoys.shape[-1] != refs.shape[-1]):
+            raise ValueError(f"decoy dim {decoys.dtype}{decoys.shape} does "
+                             f"not match refs {refs.dtype}{refs.shape}")
         num_decoys = int(decoys.shape[0])
-        bank = jnp.concatenate([decoys, refs], axis=0)
-    num_rows = int(bank.shape[0])
+        blocks.insert(0, stored(decoys))
+    store = blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks)
+    del blocks
+    num_rows = int(store.shape[0])
 
     oms_index = None
     if precursor is not None:
@@ -351,15 +383,7 @@ def shard_database(refs: jax.Array, *, decoys: jax.Array | None = None,
                     f"decoy_precursor has {dprec.shape[0]} entries for "
                     f"{num_decoys} decoys")
         oms_index = build_precursor_index(prec, dprec)
-        bank = bank[jnp.asarray(oms_index.perm)]
-
-    if pack == "auto":
-        packed = dim % 32 == 0
-    else:
-        packed = bool(pack)
-        if packed and dim % 32 != 0:
-            raise ValueError(f"pack=True requires D % 32 == 0, got D={dim}")
-    store = bitpack_bipolar(bank) if packed else bank.astype(jnp.int8)
+        store = store[jnp.asarray(oms_index.perm)]
 
     mesh_n = mesh.shape[axis] if (mesh is not None and axis in mesh.shape) else 1
     emu = int(emulate_shards or 1)
@@ -414,9 +438,9 @@ def _sharded_search_fn(mesh: Mesh, axis: str, shard_rows: int, num_rows: int,
         idx_all = jax.lax.all_gather(gidx, axis, axis=1, tiled=True)
         return _merge_topk(vals_all, idx_all, k)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(q_spec, P(axis, None)),
-        out_specs=(q_spec, q_spec), check_rep=False))
+        out_specs=(q_spec, q_spec), check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -437,10 +461,10 @@ def _sharded_oms_fn(mesh: Mesh, axis: str, shard_rows: int, num_rows: int,
         idx_all = jax.lax.all_gather(gidx, axis, axis=1, tiled=True)
         return _merge_topk(vals_all, idx_all, k)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, band_spec, band_spec, P(axis, None)),
-        out_specs=(q_spec, q_spec), check_rep=False))
+        out_specs=(q_spec, q_spec), check_vma=False))
 
 
 def encode_queries(db: ShardedDatabase, queries: jax.Array) -> jax.Array:
@@ -751,9 +775,9 @@ def _sharded_e2e_fn(mesh: Mesh, axis: str, shard_rows: int, num_rows: int,
         idx_all = jax.lax.all_gather(idx + base, axis, axis=1, tiled=True)
         return _merge_topk(vals_all, idx_all, k)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(q_spec, rep, rep, P(axis, None)),
-        out_specs=(q_spec, q_spec), check_rep=False))
+        out_specs=(q_spec, q_spec), check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -775,10 +799,10 @@ def _sharded_oms_e2e_fn(mesh: Mesh, axis: str, shard_rows: int,
         idx_all = jax.lax.all_gather(gidx, axis, axis=1, tiled=True)
         return _merge_topk(vals_all, idx_all, k)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, rep, rep, band_spec, band_spec, P(axis, None)),
-        out_specs=(q_spec, q_spec), check_rep=False))
+        out_specs=(q_spec, q_spec), check_vma=False))
 
 
 def search_database_levels(db: ShardedDatabase, enc: QueryEncoder,

@@ -37,13 +37,13 @@ OPS = ("topk_hamming", "topk_hamming_banded", "encode_search",
 # candidate even when absent from the grid)
 _GRIDS_QUICK: dict[str, dict[str, tuple[int, ...]]] = {
     "topk_hamming": {"block_q": (32, 128), "block_r": (128, 256),
-                     "word_chunk": (32,)},
+                     "word_chunk": (128,)},
     "topk_hamming_banded": {"block_q": (8, 32), "block_r": (128,),
-                            "word_chunk": (32,)},
+                            "word_chunk": (128,)},
     "encode_search": {"block_q": (8, 32), "block_r": (128, 256),
-                      "block_f": (128,), "word_chunk": (32,)},
+                      "block_f": (128,), "word_chunk": (128,)},
     "encode_search_banded": {"block_q": (8, 32), "block_r": (128,),
-                             "block_f": (128,), "word_chunk": (32,)},
+                             "block_f": (128,), "word_chunk": (128,)},
     "hd_encode": {"block_b": (8, 32), "block_d": (128, 256),
                   "block_f": (128,)},
     "imc_mvm": {"block_q": (32, 128), "block_r": (128,),
@@ -52,15 +52,15 @@ _GRIDS_QUICK: dict[str, dict[str, tuple[int, ...]]] = {
 
 _GRIDS_FULL: dict[str, dict[str, tuple[int, ...]]] = {
     "topk_hamming": {"block_q": (8, 32, 128), "block_r": (128, 256, 512),
-                     "word_chunk": (8, 16, 32)},
+                     "word_chunk": (128, 256)},
     "topk_hamming_banded": {"block_q": (8, 16, 32), "block_r": (128,),
-                            "word_chunk": (8, 16, 32)},
+                            "word_chunk": (128, 256)},
     "encode_search": {"block_q": (8, 16, 32), "block_r": (128, 256),
-                      "block_f": (32, 128), "word_chunk": (16, 32)},
+                      "block_f": (128, 256), "word_chunk": (128, 256)},
     "encode_search_banded": {"block_q": (8, 16, 32), "block_r": (128,),
-                             "block_f": (32, 128), "word_chunk": (16, 32)},
+                             "block_f": (128, 256), "word_chunk": (128, 256)},
     "hd_encode": {"block_b": (8, 16, 32), "block_d": (128, 256, 512),
-                  "block_f": (32, 128)},
+                  "block_f": (128, 256)},
     "imc_mvm": {"block_q": (8, 32, 128), "block_r": (128, 256),
                 "tile_cols": (128,)},
 }

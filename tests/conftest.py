@@ -5,82 +5,12 @@ import os
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
+from hypothesis import settings
 
 jax.config.update("jax_enable_x64", False)
 
 
-# ---------------------------------------------------------------------------
-# hypothesis gate: the property tests in test_hd_encoding.py use a small
-# slice of the hypothesis API (@given / @settings / st.integers). When the
-# real package is absent (the pinned accelerator image does not ship it and
-# installs are frozen), install a deterministic micro-shim into sys.modules
-# so the suite still collects and the properties still run over a fixed
-# pseudo-random sample of the strategy space. With hypothesis installed
-# (e.g. in CI), the real engine is used and this block is a no-op.
-# ---------------------------------------------------------------------------
-
-def _install_hypothesis_shim():
-    import functools
-    import inspect
-    import random
-    import sys
-    import types
-
-    class _Integers:
-        def __init__(self, lo, hi):
-            self.lo, self.hi = lo, hi
-
-        def draw(self, rng, i):
-            if i == 0:
-                return self.lo
-            if i == 1:
-                return self.hi
-            return rng.randint(self.lo, self.hi)
-
-    class _SampledFrom:
-        def __init__(self, options):
-            self.options = list(options)
-
-        def draw(self, rng, i):
-            if i < len(self.options):
-                return self.options[i]  # cover every option first
-            return rng.choice(self.options)
-
-    def given(*strategies):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_max_examples", 10)
-                rng = random.Random(fn.__qualname__)
-                for i in range(n):
-                    drawn = [s.draw(rng, i) for s in strategies]
-                    fn(*args, *drawn, **kwargs)
-            # hide the strategy-filled params from pytest's fixture
-            # resolution (the real hypothesis does the same)
-            sig = inspect.signature(fn)
-            params = list(sig.parameters.values())[:-len(strategies)]
-            wrapper.__signature__ = sig.replace(parameters=params)
-            return wrapper
-        return deco
-
-    def settings(max_examples=10, **_ignored):
-        def deco(fn):
-            fn._max_examples = max_examples
-            return fn
-        return deco
-
-    mod = types.ModuleType("hypothesis")
-    mod.given = given
-    mod.settings = settings
-    st_mod = types.ModuleType("hypothesis.strategies")
-    st_mod.integers = lambda lo, hi: _Integers(lo, hi)
-    st_mod.sampled_from = lambda options: _SampledFrom(options)
-    mod.strategies = st_mod
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = st_mod
-
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # pragma: no cover - depends on environment
-    _install_hypothesis_shim()
+# Property tests compile a kernel on their first example, which no
+# per-example deadline survives.
+settings.register_profile("repro", deadline=None)
+settings.load_profile("repro")

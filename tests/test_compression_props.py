@@ -35,6 +35,7 @@ from repro.dist.compression import (
     topk_ef_compress,
     tree_wire_bytes,
 )
+from repro.launch.mesh import make_mesh
 
 
 def _grad_tree(seed: int, n: int):
@@ -238,7 +239,7 @@ def test_topk_wire_bytes_beat_raw_by_4x():
 # ---------------------------------------------------------------------------
 
 def test_dcn_allreduce_tree_single_pod_none_is_identity():
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     grads = _grad_tree(0, 64)
     stacked = jax.tree.map(lambda x: x[None], grads)
     red, new_ef = dcn_allreduce_tree(stacked, {}, mesh, method="none")
@@ -251,7 +252,7 @@ def test_dcn_allreduce_tree_single_pod_topk_ef_invariant():
     """Through the shard_map wrapper, the EF invariant still holds:
     reduced + residual == grads + old residual (one pod, so the psum is
     the send itself)."""
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     grads = _grad_tree(1, 64)
     err = _grad_tree(2, 64)
     stacked = jax.tree.map(lambda x: x[None], grads)
@@ -265,7 +266,7 @@ def test_dcn_allreduce_tree_single_pod_topk_ef_invariant():
 
 
 def test_dcn_allreduce_tree_rejects_unknown_method():
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     with pytest.raises(ValueError):
         dcn_allreduce_tree({"w": jnp.zeros((1, 4))}, {}, mesh,
                            method="zstd")

@@ -11,6 +11,7 @@ from repro.dist.checkpoint import CheckpointManager
 from repro.dist.compression import compress_tree, init_error_state, topk_ef_compress
 from repro.dist.sharding import DEFAULT_RULES, logical_to_spec, set_mesh
 from repro.dist.straggler import Action, HeartbeatRegistry, StragglerMonitor
+from repro.launch.mesh import make_mesh
 
 
 class TestShardingRules:
@@ -18,13 +19,13 @@ class TestShardingRules:
         set_mesh(None)
 
     def test_divisibility_fallback(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         # model axis size 1 -> everything divisible, spec uses names
         spec = logical_to_spec(("vocab", "fsdp"), (256, 128), mesh)
         assert spec == jax.sharding.PartitionSpec("model", "data")
 
     def test_missing_axis_degrades(self):
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         spec = logical_to_spec(("batch", None), (8, 4), mesh)
         # ('pod','data') degrades to ('data',) since pod doesn't exist
         assert spec == jax.sharding.PartitionSpec("data", None)
@@ -33,14 +34,14 @@ class TestShardingRules:
         devs = jax.devices()
         if len(devs) < 1:
             pytest.skip("no devices")
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         rules = DEFAULT_RULES
         # 7 not divisible by ... 1 always divides; simulate via dim check
         spec = logical_to_spec(("heads",), (7,), mesh, rules)
         assert spec == jax.sharding.PartitionSpec("model")  # 7 % 1 == 0
 
     def test_axis_used_once(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         spec = logical_to_spec(("vocab", "heads"), (256, 256), mesh)
         # both want 'model'; second falls back to replication
         assert spec == jax.sharding.PartitionSpec("model", None)
@@ -130,7 +131,7 @@ class TestCheckpoint:
         mgr = CheckpointManager(tmp_path)
         tree = _tree()
         mgr.save(1, tree)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         sh = jax.tree.map(
             lambda x: jax.sharding.NamedSharding(
                 mesh, jax.sharding.PartitionSpec(*([None] * np.ndim(x)))),
@@ -181,7 +182,7 @@ class TestCompression:
 class TestCollectiveMatmul:
     def test_ring_matmul_reduce_matches_dense(self):
         from repro.dist.collective_matmul import ring_matmul_reduce
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = make_mesh((1,), ("model",))
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=(8, 16)).astype(np.float32))
         w = jnp.asarray(rng.normal(size=(16, 4)).astype(np.float32))
@@ -191,7 +192,7 @@ class TestCollectiveMatmul:
 
     def test_ag_matmul_pipelined_matches_dense(self):
         from repro.dist.collective_matmul import ag_matmul_pipelined
-        mesh = jax.make_mesh((1,), ("model",))
+        mesh = make_mesh((1,), ("model",))
         rng = np.random.default_rng(1)
         x = jnp.asarray(rng.normal(size=(4, 8)).astype(np.float32))
         w = jnp.asarray(rng.normal(size=(8, 6)).astype(np.float32))

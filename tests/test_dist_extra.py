@@ -26,6 +26,7 @@ from repro.dist.sharding import (
     tree_shardings,
 )
 from repro.dist.straggler import Action, HeartbeatRegistry, StragglerMonitor
+from repro.launch.mesh import make_mesh
 
 
 def _tree(seed=0):
@@ -92,7 +93,7 @@ class TestRulePresets:
         (the degradation guarantee), and device_put through them must
         preserve values exactly."""
         rules = RULE_PRESETS[preset]
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         axes = {"emb": ("vocab", "fsdp"),
                 "attn": {"wq": ("fsdp", "heads", None)},
                 "scale": (None,),
@@ -167,7 +168,7 @@ class TestCompressionDeterminism:
         assert np.isfinite(np.asarray(err["w"])).all()
 
     def test_cross_pod_allreduce_1device(self):
-        mesh = jax.make_mesh((1,), ("pod",))
+        mesh = make_mesh((1,), ("pod",))
         x = jnp.arange(8, dtype=jnp.float32).reshape(1, 8)
         out = cross_pod_allreduce(x, mesh, axis="pod", method="none")
         np.testing.assert_array_equal(np.asarray(out), np.asarray(x))

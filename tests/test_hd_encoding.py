@@ -9,6 +9,7 @@ from repro.core.hd.encoding import (
     HDEncoderConfig,
     encode_batch,
     encode_batch_reference,
+    encode_bitpacked,
     make_codebooks,
     quantize_levels,
 )
@@ -60,9 +61,21 @@ class TestEncoding:
         cfg = HDEncoderConfig(dim=128, num_features=100, num_levels=8)
         id_hvs, lv_hvs = make_codebooks(cfg)
         x = _dataset(6, 100)
-        a = encode_batch(x, id_hvs, lv_hvs, block_features=32)
+        a = encode_batch(x, id_hvs, lv_hvs)
         b = encode_batch_reference(x, id_hvs, lv_hvs)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_bitpacked_chunks_match_reference(self):
+        """Chunked bank encoding, ragged last chunk included, packs the
+        reference HVs row for row."""
+        cfg = HDEncoderConfig(dim=128, num_features=100, num_levels=8)
+        id_hvs, lv_hvs = make_codebooks(cfg)
+        x = _dataset(11, 100, seed=3)
+        got = encode_bitpacked(quantize_levels(x, 8), id_hvs, lv_hvs,
+                               chunk_rows=4)
+        want = bitpack_bipolar(encode_batch_reference(x, id_hvs, lv_hvs))
+        assert got.dtype == jnp.uint32 and got.shape == (11, 4)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
     def test_zero_spectrum_is_all_minus_one(self):
         cfg = HDEncoderConfig(dim=64, num_features=16, num_levels=4)

@@ -380,7 +380,7 @@ def _run_py(code: str, devices: int = 8, timeout: int = 520):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = str(REPO / "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                           capture_output=True, text=True, timeout=timeout,
                           env=env)
@@ -392,6 +392,7 @@ def test_merged_search_bit_identical_on_8_device_mesh():
     search must still be bit-identical to a rebuilt mesh-sharded bank."""
     r = _run_py("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.serve import (DeltaBank, OMSConfig, encode_queries,
                                  merged_oms_plan, merged_oms_search_encoded,
                                  merged_search_encoded, oms_search,
@@ -410,7 +411,7 @@ def test_merged_search_bit_identical_on_8_device_mesh():
         cfg = OMSConfig(tol=15.0, open_tol=150.0)
         cat = lambda a, b: jnp.asarray(np.concatenate([a, b]))
         for model_n in (2, 4, 8):
-            mesh = jax.make_mesh((8 // model_n, model_n), ("data", "model"))
+            mesh = make_mesh((8 // model_n, model_n), ("data", "model"))
             for pack in (True, False):
                 base = shard_database(jnp.asarray(refs0),
                                       decoys=jnp.asarray(dec0),

@@ -18,7 +18,7 @@ def _run_py(code: str, devices: int = 8, timeout: int = 520):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = str(REPO / "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                           capture_output=True, text=True, timeout=timeout,
                           env=env)
@@ -30,12 +30,12 @@ def test_dryrun_machinery_small_mesh(tmp_path):
     the production mesh — proves lower/compile/analysis plumbing without the
     512-device cost."""
     r = _run_py(f"""
-        import jax
         from pathlib import Path
         import repro.launch.mesh as mesh_mod
+        from repro.launch.mesh import make_mesh
         mesh_mod.make_production_mesh = (
-            lambda multi_pod=False: jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-            if multi_pod else jax.make_mesh((2, 4), ("data", "model")))
+            lambda multi_pod=False: make_mesh((2, 2, 2), ("pod", "data", "model"))
+            if multi_pod else make_mesh((2, 4), ("data", "model")))
         import repro.launch.dryrun as dr
         import repro.configs.shapes as shp
         import dataclasses
@@ -64,6 +64,7 @@ def test_sharded_training_matches_single_device():
         from repro.models import build_model
         from repro.data.tokens import TokenPipeline
         from repro.dist.sharding import set_mesh, logical_to_sharding
+        from repro.launch.mesh import make_mesh
         from repro.train.train_step import (TrainConfig, init_train_state,
                                             make_train_step, state_axes)
 
@@ -73,7 +74,7 @@ def test_sharded_training_matches_single_device():
         losses = {}
         for mode in ("replicated", "sharded"):
             if mode == "sharded":
-                mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+                mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
                 set_mesh(mesh)
             else:
                 set_mesh(None)
@@ -115,6 +116,7 @@ def test_hierarchical_train_step_on_pod_mesh():
         from repro.data.tokens import TokenPipeline
         from repro.dist.sharding import (set_mesh, is_axes_leaf,
                                          logical_to_sharding)
+        from repro.launch.mesh import make_mesh
         from repro.train.train_step import (TrainConfig, init_train_state,
                                             make_train_step, state_axes)
 
@@ -144,7 +146,7 @@ def test_hierarchical_train_step_on_pod_mesh():
             set_mesh(None)
             return raw, state, ls
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
         # defaults on a pod mesh keep the pre-hierarchy global reduction
         # (an uncompressed shard_map hop would cost memory for nothing)
@@ -180,7 +182,8 @@ def test_compressed_cross_pod_allreduce():
         import jax, numpy as np
         import jax.numpy as jnp
         from repro.dist.compression import cross_pod_allreduce
-        mesh = jax.make_mesh((8,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("pod",))
         x = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
         xs = jax.device_put(x, jax.sharding.NamedSharding(
             mesh, jax.sharding.PartitionSpec("pod", None)))

@@ -108,6 +108,24 @@ def test_single_device_database_path():
     np.testing.assert_array_equal(np.asarray(vals), np.asarray(ov))
 
 
+def test_shard_database_takes_packed_blocks():
+    """Already bit-packed refs/decoys (the chunked bank encoder's output)
+    build the same bank as bipolar HVs, exact and precursor-sorted."""
+    rng = np.random.default_rng(41)
+    refs, dec = _bipolar(rng, (37, 64)), _bipolar(rng, (21, 64))
+    prec = rng.uniform(400, 1600, 37).astype(np.float32)
+    for kw in ({}, {"precursor": prec, "decoy_precursor": prec[:21]}):
+        a = shard_database(refs, decoys=dec, **kw)
+        b = shard_database(bitpack_bipolar(refs),
+                           decoys=bitpack_bipolar(dec), dim=64, **kw)
+        assert b.packed and b.dim == 64 and b.num_decoys == 21
+        np.testing.assert_array_equal(np.asarray(a.data), np.asarray(b.data))
+    with pytest.raises(ValueError, match="dim=64"):
+        shard_database(bitpack_bipolar(refs))
+    with pytest.raises(ValueError, match="decoy dim"):
+        shard_database(bitpack_bipolar(refs), decoys=dec, dim=64)
+
+
 def test_k_exceeding_shard_rows_raises():
     rng = np.random.default_rng(13)
     refs = _bipolar(rng, (8, 32))
@@ -441,7 +459,7 @@ def _run_py(code: str, devices: int = 8, timeout: int = 520):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = str(REPO / "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                           capture_output=True, text=True, timeout=timeout,
                           env=env)
@@ -451,11 +469,12 @@ def _run_py(code: str, devices: int = 8, timeout: int = 520):
 def test_sharded_search_bit_identical_on_8_device_mesh():
     r = _run_py("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.core.hd.similarity import topk_search
         from repro.serve import shard_database, search_database
         rng = np.random.default_rng(1)
         for model_n in (2, 4, 8):
-            mesh = jax.make_mesh((8 // model_n, model_n), ("data", "model"))
+            mesh = make_mesh((8 // model_n, model_n), ("data", "model"))
             for R, D in [(61, 32), (64, 64), (37, 48)]:
                 refs = jnp.asarray(rng.choice([-1, 1], (R, D)).astype(np.int8))
                 q = jnp.asarray(rng.choice([-1, 1], (16, D)).astype(np.int8))
@@ -478,6 +497,7 @@ def test_oms_search_bit_identical_on_8_device_mesh():
     banded kernel per shard) vs the single-device masked path."""
     r = _run_py("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.serve import OMSConfig, oms_search, shard_database
         rng = np.random.default_rng(2)
         R, D, Q, k = 150, 64, 16, 5
@@ -490,7 +510,7 @@ def test_oms_search_bit_identical_on_8_device_mesh():
         ref_db = shard_database(refs, decoys=decoys, precursor=prec)
         oi, ov, _ = oms_search(ref_db, q, qprec, k, cfg)
         for model_n in (2, 4, 8):
-            mesh = jax.make_mesh((8 // model_n, model_n), ("data", "model"))
+            mesh = make_mesh((8 // model_n, model_n), ("data", "model"))
             for pack in (True, False):
                 for fused in (False, True):
                     db = shard_database(refs, decoys=decoys, mesh=mesh,
@@ -509,7 +529,7 @@ def test_serve_db_cli_on_8_device_mesh():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(REPO / "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve_db", "--reduced"],
         capture_output=True, text=True, timeout=520, env=env)

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from repro.core.hd.similarity import topk_search
+from repro.launch.mesh import make_mesh
 from repro.serve import (
     BankRegistry,
     DBSearchServer,
@@ -398,7 +399,7 @@ def test_shard_database_rejects_mesh_plus_emulation():
     refs = _bipolar(rng, (8, 32))
     if len(jax.devices()) > 1:  # pragma: no cover - single-device tier-1
         pytest.skip("tier-1 is single-device")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # size-1 mesh axis degrades to local: emulation is then allowed
     db = shard_database(refs, mesh=mesh, emulate_shards=2)
     assert db.mesh is None and db.num_shards == 2
@@ -412,7 +413,7 @@ def _run_py(code: str, devices: int = 8, timeout: int = 520):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = str(REPO / "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                           capture_output=True, text=True, timeout=timeout,
                           env=env)
@@ -425,9 +426,10 @@ def test_multi_tenant_cached_serving_on_8_device_mesh():
     bit-identical to each tenant's unsharded oracle."""
     r = _run_py("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.core.hd.similarity import topk_search
         from repro.serve import BankRegistry, DBSearchServer
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        mesh = make_mesh((1, 8), ("data", "model"))
         rng = np.random.default_rng(3)
         reg = BankRegistry(mesh=mesh, max_banks=2)
         banks, queries = {}, {}
@@ -468,7 +470,7 @@ def test_serve_db_cli_multi_tenant_on_8_device_mesh():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(REPO / "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve_db", "--reduced",
          "--tenants", "2", "--buckets", "2", "--cache-mb", "8",

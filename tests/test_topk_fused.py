@@ -138,13 +138,13 @@ class TestFusedEdges:
         _assert_same(a, b)
 
     def test_word_padding_is_harmless(self):
-        """W not a multiple of word_chunk pads with zero words on both
-        operands (XOR -> 0 -> popcount 0)."""
+        """W wider than one word_chunk but not a multiple of it pads with
+        zero words on both operands (XOR -> 0 -> popcount 0)."""
         rng = np.random.default_rng(4)
-        qp = jnp.asarray(rng.integers(0, 2**32, (6, 5), dtype=np.uint32))
-        rp = jnp.asarray(rng.integers(0, 2**32, (40, 5), dtype=np.uint32))
-        got = topk_hamming_pallas(qp, rp, dim=160, k=4, word_chunk=4)
-        want = topk_hamming_ref(qp, rp, 160, 4)
+        qp = jnp.asarray(rng.integers(0, 2**32, (6, 130), dtype=np.uint32))
+        rp = jnp.asarray(rng.integers(0, 2**32, (40, 130), dtype=np.uint32))
+        got = topk_hamming_pallas(qp, rp, dim=130 * 32, k=4, word_chunk=128)
+        want = topk_hamming_ref(qp, rp, 130 * 32, 4)
         _assert_same(got, want)
 
 

@@ -71,7 +71,7 @@ def test_shape_bucket_pow2():
 def test_table_roundtrip(tmp_path):
     t = _mk_table(peak_flops=1e11, hbm_bw=2e10)
     t.set_entry("topk_hamming", (100, 8000, 32),
-                {"block_q": 32, "block_r": 256, "word_chunk": 32},
+                {"block_q": 32, "block_r": 256, "word_chunk": 128},
                 us=10.0, default_us=20.0)
     path = t.save(tmp_path / "table.json")
     loaded = load_table(path)
@@ -79,7 +79,7 @@ def test_table_roundtrip(tmp_path):
     assert loaded.device_kind == t.device_kind
     assert loaded.ceilings["peak_flops"] == 1e11
     assert loaded.lookup("topk_hamming", (128, 8192, 32)) == {
-        "block_q": 32, "block_r": 256, "word_chunk": 32}
+        "block_q": 32, "block_r": 256, "word_chunk": 128}
     # a different bucket misses
     assert loaded.lookup("topk_hamming", (128, 1024, 32)) is None
 
@@ -102,13 +102,17 @@ def test_partial_table_falls_back(tmp_path):
 def test_misaligned_entry_dropped_at_load(tmp_path, caplog):
     t = _mk_table()
     t.set_entry("topk_hamming", (8, 128, 4),
-                {"block_q": 7, "block_r": 128, "word_chunk": 32})
+                {"block_q": 7, "block_r": 128, "word_chunk": 128})
     t.set_entry("topk_hamming", (8, 256, 4),
+                {"block_q": 8, "block_r": 128, "word_chunk": 128})
+    # a lane slice Mosaic cannot prove 128-aligned
+    t.set_entry("topk_hamming", (8, 512, 4),
                 {"block_q": 8, "block_r": 128, "word_chunk": 32})
     path = t.save(tmp_path / "table.json")
     with caplog.at_level("WARNING", logger="repro.tune"):
         loaded = load_table(path)
     assert loaded.lookup("topk_hamming", (8, 128, 4)) is None  # dropped
+    assert loaded.lookup("topk_hamming", (8, 512, 4)) is None  # dropped
     assert loaded.lookup("topk_hamming", (8, 256, 4)) is not None  # kept
     assert any("misaligned" in r.message for r in caplog.records)
 
@@ -123,7 +127,7 @@ def test_unknown_op_dropped_at_load(tmp_path):
 def test_device_kind_mismatch_ignored(tmp_path, caplog):
     t = _mk_table(kind="TPU v99")
     t.set_entry("topk_hamming", (8, 128, 4),
-                {"block_q": 8, "block_r": 128, "word_chunk": 8})
+                {"block_q": 8, "block_r": 128, "word_chunk": 256})
     set_active_table(t.save(tmp_path / "table.json"))
     with caplog.at_level("WARNING", logger="repro.tune"):
         assert lookup_blocks("topk_hamming", (8, 128, 4)) is None
@@ -135,13 +139,13 @@ def test_device_kind_mismatch_ignored(tmp_path, caplog):
 def test_env_var_activation(tmp_path, monkeypatch):
     t = _mk_table()
     t.set_entry("topk_hamming", (8, 128, 4),
-                {"block_q": 16, "block_r": 128, "word_chunk": 8})
+                {"block_q": 16, "block_r": 128, "word_chunk": 256})
     path = t.save(tmp_path / "table.json")
     assert lookup_blocks("topk_hamming", (8, 128, 4)) is None
     monkeypatch.setenv(tune_table.ENV_VAR, str(path))
     # env change is picked up without an explicit reset()
     assert lookup_blocks("topk_hamming", (8, 128, 4)) == {
-        "block_q": 16, "block_r": 128, "word_chunk": 8}
+        "block_q": 16, "block_r": 128, "word_chunk": 256}
     monkeypatch.delenv(tune_table.ENV_VAR)
     assert lookup_blocks("topk_hamming", (8, 128, 4)) is None
 
@@ -149,13 +153,13 @@ def test_env_var_activation(tmp_path, monkeypatch):
 def test_resolve_blocks_precedence():
     t = _mk_table()
     t.set_entry("topk_hamming", (8, 128, 4),
-                {"block_q": 16, "block_r": 256, "word_chunk": 16})
+                {"block_q": 16, "block_r": 256, "word_chunk": 256})
     set_active_table(t)
     # table beats defaults
     assert resolve_blocks("topk_hamming", (8, 128, 4),
                           {"block_q": None, "block_r": None,
                            "word_chunk": None}) == {
-        "block_q": 16, "block_r": 256, "word_chunk": 16}
+        "block_q": 16, "block_r": 256, "word_chunk": 256}
     # explicit beats table
     cfg = resolve_blocks("topk_hamming", (8, 128, 4),
                          {"block_q": 32, "block_r": None, "word_chunk": None})
@@ -245,10 +249,10 @@ def test_imc_mvm_rejects_misaligned_blocks():
 
 # a deliberately non-default (but aligned) tuned config per op
 _TUNED = {
-    "topk_hamming": {"block_q": 16, "block_r": 256, "word_chunk": 8},
-    "topk_hamming_banded": {"block_q": 16, "block_r": 128, "word_chunk": 8},
-    "encode_search": {"block_q": 16, "block_r": 256, "block_f": 32,
-                      "word_chunk": 16},
+    "topk_hamming": {"block_q": 16, "block_r": 256, "word_chunk": 256},
+    "topk_hamming_banded": {"block_q": 16, "block_r": 128, "word_chunk": 256},
+    "encode_search": {"block_q": 16, "block_r": 256, "block_f": 256,
+                      "word_chunk": 256},
 }
 
 
@@ -341,8 +345,8 @@ def test_shard_database_block_plumbing():
     queries = jnp.asarray(bip((4, 64)))
     db0 = shard_database(refs, fused=True)
     db1 = shard_database(refs, fused=True, block_q=16, block_r=256,
-                         word_chunk=8)
-    assert (db1.block_q, db1.block_r, db1.word_chunk) == (16, 256, 8)
+                         word_chunk=256)
+    assert (db1.block_q, db1.block_r, db1.word_chunk) == (16, 256, 256)
     idx0, val0 = search_database(db0, queries, 3)
     idx1, val1 = search_database(db1, queries, 3)
     np.testing.assert_array_equal(np.asarray(idx0), np.asarray(idx1))
