@@ -6,6 +6,8 @@ device, then drives the main serving path once through
 ``DBSearchServer`` with the fused Pallas kernels, checking every result
 bit for bit against an independent oracle:
 
+  * the encoders: 384 bank rows of the first pass (decoys too) and all
+    512 queries — against the gather oracle ``encode_batch_reference``;
   * exact search: 512 queries, fused streaming top-k, continuous batching
     — against the unfused jnp top-k over the same bank, plus a numpy
     XOR+popcount recomputation for 8 queries;
@@ -33,6 +35,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import functools
+import gc
 import json
 import os
 import sys
@@ -46,10 +49,12 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.core.hd.encoding import (  # noqa: E402
+    encode_batch_reference,
     encode_bitpacked,
     encode_levels_batch,
     quantize_levels,
 )
+from repro.core.hd.similarity import bitpack_bipolar  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.serve import (  # noqa: E402
@@ -77,6 +82,12 @@ K = 4
 NUM_QUERIES = 512
 BATCH = 128
 NUMPY_CHECKED = 8
+# bank rows of pass 1 checked against the gather oracle: the first rows,
+# both sides of the first 8192-row encoder chunk boundary, and the ragged
+# last chunk (encode_bitpacked)
+ENCODER_CHECKED = np.r_[0:128, 8192 - 64:8192 + 64,
+                        IDENTITIES - 128:IDENTITIES]
+ORACLE_ROWS = 16  # encode_batch_reference holds (rows, F, D) int32
 # OMS window: query - ref in (-2.5, +28); 30.5 of the 1,200 Da precursor
 # range, i.e. a candidate fraction near iPRG2012's 0.025
 OMS = OMSConfig(tol=2.5, open_tol=28.0)
@@ -120,12 +131,29 @@ class Library:
 
 
 @functools.partial(jax.jit, static_argnums=0)
-def generate_levels(cfg: SyntheticMSConfig, seed):
+def generate_levels(cfg: SyntheticMSConfig, seed, rows):
     """One synthetic pass as one compiled program (the seed is traced, so
-    every pass reuses it): quantized levels, precursors, template ids."""
+    every pass reuses it): quantized levels, precursors, template ids, and
+    the raw spectra of ``rows`` for the encoder check."""
     ds = generate_dataset(dataclasses.replace(cfg, seed=seed))
     return (quantize_levels(ds.spectra, NUM_LEVELS), ds.precursor,
-            ds.identity)
+            ds.identity, ds.spectra[rows])
+
+
+reference_encode = jax.jit(encode_batch_reference)
+
+
+def check_encoder(label: str, feats, got, enc: QueryEncoder) -> None:
+    """Packed encoder output for raw spectra ``feats`` must equal the
+    gather oracle ``encode_batch_reference``, taken ORACLE_ROWS at a time
+    for its (rows, F, D) intermediate."""
+    want = jnp.concatenate([bitpack_bipolar(reference_encode(
+        feats[lo:lo + ORACLE_ROWS], enc.id_hvs, enc.level_hvs))
+        for lo in range(0, feats.shape[0], ORACLE_ROWS)])
+    bad = int(jnp.sum(jnp.any(got != want, axis=1)))
+    print(f"{label} vs encode_batch_reference: {bad} mismatches over "
+          f"{feats.shape[0]} rows", flush=True)
+    check(bad == 0, f"{label}: {bad} rows differ from encode_batch_reference")
 
 
 def build_library(seed: int) -> Library:
@@ -136,9 +164,11 @@ def build_library(seed: int) -> Library:
     base = SyntheticMSConfig(num_identities=IDENTITIES,
                              spectra_per_identity=1, num_bins=NUM_BINS)
     targets, decoys, prec = [], [], []
+    checked = jnp.asarray(ENCODER_CHECKED)
     for p in range(PASSES):
         t0 = time.perf_counter()
-        levels, precursor, _ = generate_levels(base, seed * 1000 + p)
+        levels, precursor, _, feats = generate_levels(
+            base, seed * 1000 + p, checked)
         targets.append(encode_bitpacked(levels, enc.id_hvs, enc.level_hvs))
         # decoys reverse the m/z axis (make_decoys); quantizing commutes
         decoys.append(encode_bitpacked(levels[:, ::-1], enc.id_hvs,
@@ -148,6 +178,11 @@ def build_library(seed: int) -> Library:
         jax.block_until_ready(decoys[-1])
         setup(f"library pass {p + 1}/{PASSES} (generate + encode "
               f"{2 * IDENTITIES} rows)", t0)
+        if p == 0:
+            check_encoder("bank encoder, target rows", feats,
+                          targets[-1][checked], enc)
+            check_encoder("bank encoder, decoy rows", feats[:, ::-1],
+                          decoys[-1][checked], enc)
     t0 = time.perf_counter()
     targets = jnp.concatenate(targets)
     decoys = jnp.concatenate(decoys)
@@ -157,11 +192,13 @@ def build_library(seed: int) -> Library:
     t0 = time.perf_counter()
     qcfg = dataclasses.replace(base, modification_rate=0.3,
                                modification_mass_range=MOD_MASS)
-    levels, precursor, identity = generate_levels(qcfg, seed * 1000 + 999)
     pick = np.sort(np.random.default_rng(seed).choice(
         IDENTITIES, NUM_QUERIES, replace=False))
+    levels, precursor, identity, q_feats = generate_levels(
+        qcfg, seed * 1000 + 999, jnp.asarray(pick))
     q_levels = levels[jnp.asarray(pick)]
     q_hv = encode_levels_batch(q_levels, enc.id_hvs, enc.level_hvs)
+    check_encoder("query encoder", q_feats, bitpack_bipolar(q_hv), enc)
     lib = Library(targets=targets, decoys=decoys,
                   precursor=np.concatenate(prec), encoder=enc,
                   q_levels=np.asarray(q_levels, np.int32),
@@ -232,7 +269,7 @@ def numpy_topk(db, q_enc: np.ndarray, k: int):
 
 def exact_phases(lib: Library) -> None:
     t0 = time.perf_counter()
-    db = shard_database(lib.targets, decoys=lib.decoys, dim=DIM, fused=True,
+    db = shard_database(lib.targets, decoys=lib.decoys, fused=True,
                         block_q=BATCH)
     jax.block_until_ready(db.data)
     setup("exact bank", t0)
@@ -272,7 +309,7 @@ def exact_phases(lib: Library) -> None:
 
 def oms_phase(lib: Library) -> None:
     t0 = time.perf_counter()
-    db = shard_database(lib.targets, decoys=lib.decoys, dim=DIM, fused=True,
+    db = shard_database(lib.targets, decoys=lib.decoys, fused=True,
                         precursor=lib.precursor, block_q=BATCH)
     jax.block_until_ready(db.data)
     setup("precursor-sorted OMS bank", t0)
@@ -318,12 +355,20 @@ def masked_oms_oracle(db):
     return run
 
 
+def free_served_banks() -> None:
+    """A served bank sits in a reference cycle (DBSearchServer and its
+    SearchExecutor point at each other), so it outlives its phase until
+    the cyclic collector runs. Collect it before the next phase builds
+    another bank, so peak device memory does not depend on when that is."""
+    gc.collect()
+
+
 def sharded_phase(lib: Library, n_chips: int) -> None:
     mesh = make_mesh((1, n_chips), ("data", "model"))
     dev0 = jax.devices()[0]
     for oms in (False, True):
         label = "sharded OMS" if oms else "sharded exact"
-        kw = dict(decoys=lib.decoys, dim=DIM, fused=True, block_q=BATCH,
+        kw = dict(decoys=lib.decoys, fused=True, block_q=BATCH,
                   precursor=lib.precursor if oms else None)
         t0 = time.perf_counter()
         db = shard_database(lib.targets, mesh=mesh, **kw)
@@ -366,6 +411,7 @@ def sharded_phase(lib: Library, n_chips: int) -> None:
                 want)
         report_ids(label, res, lib)
         del db, one, server
+        free_served_banks()
 
 
 def main(argv=None) -> int:
@@ -405,6 +451,7 @@ def main(argv=None) -> int:
     lib = build_library(args.seed)
     if args.chips == 1:
         exact_phases(lib)
+        free_served_banks()
         oms_phase(lib)
     else:
         sharded_phase(lib, args.chips)

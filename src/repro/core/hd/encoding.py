@@ -161,6 +161,11 @@ def encode_batch(
     return encode_levels_batch(levels, id_hvs, level_hvs)
 
 
+# rows per bank-encoder chunk: the (chunk, D) float32 accumulator is 256 MiB
+# at D=8192
+_CHUNK_ROWS = 8192
+
+
 @partial(jax.jit, static_argnames=("rows",))
 def _encode_pack_rows(levels, start, id_hvs, level_hvs, *, rows: int):
     blk = jax.lax.dynamic_slice_in_dim(levels, start, rows)
@@ -171,12 +176,10 @@ def encode_bitpacked(
     levels: jax.Array,
     id_hvs: jax.Array,
     level_hvs: jax.Array,
-    *,
-    chunk_rows: int = 8192,
 ) -> jax.Array:
     """(N, F) levels -> (N, D/32) uint32 bit-packed Eq. 1 hypervectors.
 
-    Encodes ``chunk_rows`` rows at a time with one compiled program (the
+    Encodes ``_CHUNK_ROWS`` rows at a time with one compiled program (the
     chunk start is traced; a ragged last chunk is taken from the end and
     trimmed): the unpacked (chunk, D) HVs are the largest intermediate, so
     a library of any size encodes in bounded memory and only packed words
@@ -184,7 +187,7 @@ def encode_bitpacked(
     (...))`` row for row.
     """
     n = int(levels.shape[0])
-    chunk = max(1, min(chunk_rows, n))
+    chunk = max(1, min(_CHUNK_ROWS, n))
     starts = list(range(0, n, chunk))
     tail = n - starts[-1]
     starts[-1] = n - chunk

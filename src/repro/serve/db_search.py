@@ -289,11 +289,10 @@ def shard_database(refs: jax.Array, *, decoys: jax.Array | None = None,
                    decoy_precursor: np.ndarray | None = None,
                    block_q: int | None = None,
                    block_r: int | None = None,
-                   word_chunk: int | None = None,
-                   dim: int | None = None
+                   word_chunk: int | None = None
                    ) -> ShardedDatabase:
     """Build a :class:`ShardedDatabase` from bipolar (R, D) reference HVs,
-    or from already bit-packed (R, D/32) uint32 words with ``dim=D``.
+    or from already bit-packed (R, D/32) uint32 words (D = 32 words).
 
     decoys: optional decoy HVs in the same form as ``refs``, stored
       *before* the targets (see module docstring for why the order
@@ -322,8 +321,6 @@ def shard_database(refs: jax.Array, *, decoys: jax.Array | None = None,
       trace time (:mod:`repro.kernels.block_utils`). The OMS banded
       routes keep their fixed ``block_q``/``block_r`` (the host-side tile
       budget is priced in those units) regardless of these overrides.
-    dim: the HD dimensionality of packed uint32 inputs (required for them,
-      ignored otherwise).
     The padded bank is device_put row-sharded over ``axis`` when a mesh
     with that axis (size > 1) is supplied; otherwise it stays local.
     """
@@ -333,9 +330,7 @@ def shard_database(refs: jax.Array, *, decoys: jax.Array | None = None,
             validate_block("topk_hamming", _name, _val)
     prepacked = refs.dtype == jnp.uint32
     if prepacked:
-        if dim is None or dim != 32 * int(refs.shape[-1]):
-            raise ValueError(f"packed refs of {refs.shape[-1]} words need "
-                             f"dim={32 * int(refs.shape[-1])}, got {dim}")
+        dim = 32 * int(refs.shape[-1])
         if pack is False:
             raise ValueError("pack=False cannot take packed uint32 refs")
         packed = True

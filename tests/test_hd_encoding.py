@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core.hd import encoding
 from repro.core.hd.encoding import (
     HDEncoderConfig,
     encode_batch,
@@ -65,14 +66,14 @@ class TestEncoding:
         b = encode_batch_reference(x, id_hvs, lv_hvs)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    def test_bitpacked_chunks_match_reference(self):
+    def test_bitpacked_chunks_match_reference(self, monkeypatch):
         """Chunked bank encoding, ragged last chunk included, packs the
         reference HVs row for row."""
+        monkeypatch.setattr(encoding, "_CHUNK_ROWS", 4)
         cfg = HDEncoderConfig(dim=128, num_features=100, num_levels=8)
         id_hvs, lv_hvs = make_codebooks(cfg)
         x = _dataset(11, 100, seed=3)
-        got = encode_bitpacked(quantize_levels(x, 8), id_hvs, lv_hvs,
-                               chunk_rows=4)
+        got = encode_bitpacked(quantize_levels(x, 8), id_hvs, lv_hvs)
         want = bitpack_bipolar(encode_batch_reference(x, id_hvs, lv_hvs))
         assert got.dtype == jnp.uint32 and got.shape == (11, 4)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -117,13 +117,11 @@ def test_shard_database_takes_packed_blocks():
     for kw in ({}, {"precursor": prec, "decoy_precursor": prec[:21]}):
         a = shard_database(refs, decoys=dec, **kw)
         b = shard_database(bitpack_bipolar(refs),
-                           decoys=bitpack_bipolar(dec), dim=64, **kw)
+                           decoys=bitpack_bipolar(dec), **kw)
         assert b.packed and b.dim == 64 and b.num_decoys == 21
         np.testing.assert_array_equal(np.asarray(a.data), np.asarray(b.data))
-    with pytest.raises(ValueError, match="dim=64"):
-        shard_database(bitpack_bipolar(refs))
     with pytest.raises(ValueError, match="decoy dim"):
-        shard_database(bitpack_bipolar(refs), decoys=dec, dim=64)
+        shard_database(bitpack_bipolar(refs), decoys=dec)
 
 
 def test_k_exceeding_shard_rows_raises():

@@ -263,10 +263,17 @@ def _install(op, shape):
 
 
 @pytest.mark.parametrize("packed", [True, False])
-@pytest.mark.parametrize("q_n,r_n", [(5, 100), (8, 300), (13, 257)])
-def test_topk_tuned_bit_identical(packed, q_n, r_n):
+@pytest.mark.parametrize("q_n,r_n,packed_dim", [
+    pytest.param(5, 100, 64, id="5-100"),
+    pytest.param(8, 300, 64, id="8-300"),
+    pytest.param(13, 257, 64, id="13-257"),
+    # 260 words: the default and tuned word chunks (128, 256) walk
+    # different chunk counts and word paddings
+    pytest.param(6, 130, 8320, id="6-130-W260"),
+])
+def test_topk_tuned_bit_identical(packed, q_n, r_n, packed_dim):
     from repro.kernels.topk_hamming import topk_hamming_pallas
-    dim = 96 if not packed else 64
+    dim = packed_dim if packed else packed_dim + 32
     qb = jnp.asarray(bip((q_n, dim)))
     rb = jnp.asarray(bip((r_n, dim)))
     q = bitpack_bipolar(qb) if packed else qb
