@@ -1,0 +1,8 @@
+"""dispatch_ms.offline: mean per window batch of the program's
+``serve.dispatch`` span; see bench/program_spans.py."""
+
+import program_spans
+
+
+def read(rec):
+    return program_spans.stage_ms(rec, "serve.dispatch")

@@ -233,6 +233,7 @@ def encode_search_pallas_call(
         ],
         scratch_shapes=_scratch(block_q, block_r, k, D, W, packed),
         interpret=interpret,
+        name="encode_search",
     )(num_valid, levels, id_hvs, level_hvs, r)
 
 
@@ -341,4 +342,5 @@ def encode_search_banded_pallas_call(
             jax.ShapeDtypeStruct((Q, k), jnp.int32),
         ],
         interpret=interpret,
+        name="encode_search_banded",
     )(tile_base, levels, id_hvs, level_hvs, r, starts, ends)

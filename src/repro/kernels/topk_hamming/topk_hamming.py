@@ -152,6 +152,7 @@ def topk_hamming_pallas_call(
             pltpu.VMEM((block_q, block_r), jnp.int32),
         ],
         interpret=interpret,
+        name="topk_hamming",
     )(num_valid, q, r)
 
 
@@ -262,4 +263,5 @@ def topk_hamming_banded_pallas_call(
             jax.ShapeDtypeStruct((Q, k), jnp.int32),
         ],
         interpret=interpret,
+        name="topk_hamming_banded",
     )(tile_base, q, r, starts, ends)

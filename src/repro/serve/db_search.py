@@ -62,6 +62,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import itertools
 import time
 from typing import Callable, Sequence
 
@@ -82,6 +83,7 @@ from repro.core.hd.similarity import (
     topk_search,
 )
 from repro.kernels.block_utils import validate_block
+from repro.serve import trace
 from repro.serve.cache import BankRegistry, QueryHVCache
 from repro.serve.clustering import ClusteringConfig, StreamingClusterer
 from repro.serve.oms import (
@@ -1079,6 +1081,9 @@ class ClusterBatchHandle:
     struct_version: int          # clusterer structure at dispatch
 
 
+_BATCH_IDS = itertools.count()  # batch numbers, unique in the process
+
+
 class SearchExecutor:
     """The production device executor behind the scheduler seam.
 
@@ -1100,6 +1105,12 @@ class SearchExecutor:
         routes FDR, fills per-request results, stamps ``t_done``, records
         latency stats, and drops cancelled requests.
 
+    Each call is one span of :mod:`repro.serve.trace` (``serve.dispatch``,
+    ``serve.finalize``, keyed by the batch number and first request id),
+    with a child span per stage: ``.plan`` (OMS), ``.assemble`` and
+    ``.launch`` under dispatch; ``.wait`` (the host blocked on the
+    device), ``.fdr`` and ``.results`` under finalize.
+
     Tests replace this class with fake executors to make scheduling
     decisions deterministic — see ``tests/test_scheduler.py``.
     """
@@ -1110,41 +1121,46 @@ class SearchExecutor:
     def dispatch(self, reqs: list[Request]) -> BatchHandle:
         srv = self.server
         t = srv._clock()
+        batch = next(_BATCH_IDS)
         for r in reqs:
             r.t_dispatch = t
-        tenant = reqs[0].tenant
-        if reqs[0].kind == "cluster":
-            return self._dispatch_cluster(reqs, tenant)
-        db, delta = srv.banks.get_with_delta(tenant)  # lazy shard-on-use
+            r.batch = batch
         n = len(reqs)
         bucket = bucket_for(n, srv.buckets)
         srv._bucket_counts[bucket] += 1
-        if srv.oms is not None:
-            return self._dispatch_oms(reqs, db, delta, n, bucket, tenant)
-        if delta is not None:
-            # merged base+delta search (bit-identical to a rebuilt bank).
-            # The fused-e2e route has no encoded intermediate to hand the
-            # delta, so delta batches take the staged pipeline — which is
-            # bit-identical to fused by the PR 7 invariant.
-            from repro.serve.delta import merged_search_encoded
-            q_enc = jax.device_put(
-                srv._encode_batch(reqs, db, bucket, tenant))
-            q_raw = jax.device_put(srv._raw_batch(reqs, bucket))
-            idx, vals = merged_search_encoded(db, delta, q_enc, q_raw,
-                                              srv.k)
-            return BatchHandle(
-                reqs=reqs, tenant=tenant, db=db, n=n, idx=idx, vals=vals,
-                num_decoys=db.num_decoys + delta.num_decoys)
-        if srv.encoder is not None and srv.fused_e2e:
-            batch = jax.device_put(srv._levels_batch(reqs, bucket))
-            idx, vals = search_database_levels(db, srv.encoder, batch,
-                                               srv.k, fused_e2e=True)
-        else:
-            batch = jax.device_put(
-                srv._encode_batch(reqs, db, bucket, tenant))
-            idx, vals = search_database_encoded(db, batch, srv.k)
-        return BatchHandle(reqs=reqs, tenant=tenant, db=db, n=n, idx=idx,
-                           vals=vals)
+        with trace.span("serve.dispatch", batch=batch, rid0=reqs[0].rid,
+                        n=n, bucket=bucket):
+            tenant = reqs[0].tenant
+            if reqs[0].kind == "cluster":
+                return self._dispatch_cluster(reqs, tenant, bucket)
+            db, delta = srv.banks.get_with_delta(tenant)  # lazy shard-on-use
+            if srv.oms is not None:
+                return self._dispatch_oms(reqs, db, delta, n, bucket, tenant)
+            num_decoys = None
+            with trace.span("serve.dispatch.assemble"):
+                if delta is not None:
+                    # merged base+delta search (bit-identical to a rebuilt
+                    # bank). The fused-e2e route has no encoded intermediate
+                    # to hand the delta, so delta batches take the staged
+                    # pipeline, which is bit-identical to the fused one.
+                    from repro.serve.delta import merged_search_encoded
+                    inputs = (srv._encode_batch(reqs, db, bucket, tenant),
+                              srv._raw_batch(reqs, bucket))
+                    search = functools.partial(merged_search_encoded, db,
+                                               delta)
+                    num_decoys = db.num_decoys + delta.num_decoys
+                elif srv.encoder is not None and srv.fused_e2e:
+                    inputs = (srv._levels_batch(reqs, bucket),)
+                    search = functools.partial(
+                        search_database_levels, db, srv.encoder,
+                        fused_e2e=True)
+                else:
+                    inputs = (srv._encode_batch(reqs, db, bucket, tenant),)
+                    search = functools.partial(search_database_encoded, db)
+            with trace.span("serve.dispatch.launch"):
+                idx, vals = search(*map(jax.device_put, inputs), srv.k)
+            return BatchHandle(reqs=reqs, tenant=tenant, db=db, n=n, idx=idx,
+                               vals=vals, num_decoys=num_decoys)
 
     def _dispatch_oms(self, reqs: list[Request], db: ShardedDatabase,
                       delta, n: int, bucket: int, tenant: str
@@ -1158,39 +1174,39 @@ class SearchExecutor:
         fused-e2e shortcut falls back to the staged pipeline for those
         batches, which is bit-identical."""
         srv = self.server
-        prec = np.asarray([r.precursor for r in reqs], np.float32)
-        order = np.argsort(prec, kind="stable")
-        inv = np.argsort(order, kind="stable")
-        prec_padded = np.concatenate(
-            [prec[order], np.full(bucket - n, prec[order][-1], np.float32)])
         num_decoys = None
-        if delta is not None:
-            from repro.serve.delta import merged_oms_plan, \
-                merged_oms_search_encoded
-            mplan = merged_oms_plan(db, delta, prec_padded, srv.oms)
-            batch = srv._encode_batch(reqs, db, bucket, tenant)
-            q_enc = jax.device_put(
-                np.concatenate([batch[:n][order], batch[n:]]))
-            raw = srv._raw_batch(reqs, bucket)
-            q_raw = jax.device_put(
-                np.concatenate([raw[:n][order], raw[n:]]))
-            idx, vals = merged_oms_search_encoded(db, delta, q_enc, q_raw,
-                                                  mplan, srv.k)
-            plan = mplan
-            num_decoys = db.num_decoys + delta.num_decoys
-        elif srv.encoder is not None and srv.fused_e2e:
-            plan = oms_plan(db, prec_padded, srv.oms)
-            batch = srv._levels_batch(reqs, bucket)
-            sorted_batch = np.concatenate([batch[:n][order], batch[n:]])
-            idx, vals = oms_search_levels(
-                db, srv.encoder, jax.device_put(sorted_batch), plan, srv.k,
-                fused_e2e=True)
-        else:
-            plan = oms_plan(db, prec_padded, srv.oms)
-            batch = srv._encode_batch(reqs, db, bucket, tenant)
-            sorted_batch = np.concatenate([batch[:n][order], batch[n:]])
-            idx, vals = oms_search_encoded(
-                db, jax.device_put(sorted_batch), plan, srv.k)
+        with trace.span("serve.dispatch.plan") as attrs:
+            prec = np.asarray([r.precursor for r in reqs], np.float32)
+            order = np.argsort(prec, kind="stable")
+            inv = np.argsort(order, kind="stable")
+            prec_padded = np.concatenate(
+                [prec[order], np.full(bucket - n, prec[order][-1],
+                                      np.float32)])
+            if delta is not None:
+                from repro.serve.delta import merged_oms_plan
+                plan = merged_oms_plan(db, delta, prec_padded, srv.oms)
+                attrs["tiles"] = int(plan.base.num_tiles)  # banded side
+            else:
+                plan = oms_plan(db, prec_padded, srv.oms)
+                attrs["tiles"] = int(plan.num_tiles)
+        with trace.span("serve.dispatch.assemble"):
+            if delta is not None:
+                from repro.serve.delta import merged_oms_search_encoded
+                inputs = (srv._encode_batch(reqs, db, bucket, tenant),
+                          srv._raw_batch(reqs, bucket))
+                search = functools.partial(merged_oms_search_encoded, db,
+                                           delta)
+                num_decoys = db.num_decoys + delta.num_decoys
+            elif srv.encoder is not None and srv.fused_e2e:
+                inputs = (srv._levels_batch(reqs, bucket),)
+                search = functools.partial(oms_search_levels, db,
+                                           srv.encoder, fused_e2e=True)
+            else:
+                inputs = (srv._encode_batch(reqs, db, bucket, tenant),)
+                search = functools.partial(oms_search_encoded, db)
+            inputs = [np.concatenate([b[:n][order], b[n:]]) for b in inputs]
+        with trace.span("serve.dispatch.launch"):
+            idx, vals = search(*map(jax.device_put, inputs), plan, srv.k)
         valid = plan.has_candidate[:n][inv]
         srv._oms_batches += 1
         srv._oms_cand_frac += plan.candidate_fraction
@@ -1200,23 +1216,20 @@ class SearchExecutor:
                            vals=vals, valid=valid, inv=inv, oms=True,
                            num_decoys=num_decoys)
 
-    def _dispatch_cluster(self, reqs: list[Request], tenant: str
-                          ) -> ClusterBatchHandle:
+    def _dispatch_cluster(self, reqs: list[Request], tenant: str,
+                          bucket: int) -> ClusterBatchHandle:
         """Clustering dispatch: launch the batch-vs-centroids distance
         matrix (device, async) against the tenant's current snapshot;
         the assign-or-spawn loop runs at finalize."""
         srv = self.server
         cl = srv.clusterers.setdefault(
             tenant, StreamingClusterer(srv.clustering))
-        n = len(reqs)
-        bucket = bucket_for(n, srv.buckets)
-        srv._bucket_counts[bucket] += 1
         hvs = np.zeros((bucket, srv.clustering.dim), np.int8)
         for i, r in enumerate(reqs):
             hvs[i] = r.query
         dists = cl.snapshot_distances(hvs)
-        return ClusterBatchHandle(reqs=reqs, tenant=tenant, n=n, hvs=hvs,
-                                  dists=dists, c0=cl.num_clusters,
+        return ClusterBatchHandle(reqs=reqs, tenant=tenant, n=len(reqs),
+                                  hvs=hvs, dists=dists, c0=cl.num_clusters,
                                   struct_version=cl.struct_version)
 
     def poll(self, handle) -> bool:
@@ -1229,8 +1242,9 @@ class SearchExecutor:
     def _finalize_cluster(self, handle: ClusterBatchHandle) -> list[Request]:
         srv = self.server
         cl = srv.clusterers[handle.tenant]
-        dists = (None if handle.dists is None
-                 else np.asarray(handle.dists)[:handle.n])  # blocks
+        with trace.span("serve.finalize.wait"):
+            dists = (None if handle.dists is None
+                     else np.asarray(handle.dists)[:handle.n])
         assigns = cl.assign_batch(handle.hvs[:handle.n], dists, handle.c0,
                                   handle.struct_version)
         t_done = srv._clock()
@@ -1251,35 +1265,43 @@ class SearchExecutor:
         return live
 
     def finalize(self, handle) -> list[Request]:
-        if isinstance(handle, ClusterBatchHandle):
-            return self._finalize_cluster(handle)
+        first = handle.reqs[0]
+        with trace.span("serve.finalize", batch=first.batch, rid0=first.rid):
+            if isinstance(handle, ClusterBatchHandle):
+                return self._finalize_cluster(handle)
+            return self._finalize_search(handle)
+
+    def _finalize_search(self, handle: BatchHandle) -> list[Request]:
         srv = self.server
         n = handle.n
-        idx = np.asarray(handle.idx)[:n]   # blocks until the device is done
-        vals = np.asarray(handle.vals)[:n]
-        if handle.inv is not None:
-            idx, vals = idx[handle.inv], vals[handle.inv]
-        valid = None if handle.valid is None else jnp.asarray(handle.valid)
-        routed = fdr_route(handle.db, jnp.asarray(idx), jnp.asarray(vals),
-                           fdr=srv.fdr, valid=valid,
-                           num_decoys=handle.num_decoys)
-        t_done = srv._clock()
-        live: list[Request] = []
-        for i, r in enumerate(handle.reqs):
-            if r.cancelled:
-                continue
-            r.result = QueryResult(
-                indices=routed.indices[i], scores=routed.scores[i],
-                is_target=bool(routed.is_target[i]),
-                accept=bool(routed.accept[i]), match=int(routed.match[i]),
-                has_candidate=(True if routed.valid is None
-                               else bool(routed.valid[i])))
-            r.t_done = t_done
-            live.append(r)
-        if live:
-            srv.stats.record_batch(live)
-            srv.tenant_stats.setdefault(
-                handle.tenant, LatencyStats()).record_batch(live)
+        with trace.span("serve.finalize.wait"):
+            idx = np.asarray(handle.idx)[:n]  # blocks until the device is done
+            vals = np.asarray(handle.vals)[:n]
+        with trace.span("serve.finalize.fdr"):
+            if handle.inv is not None:
+                idx, vals = idx[handle.inv], vals[handle.inv]
+            valid = None if handle.valid is None else jnp.asarray(handle.valid)
+            routed = fdr_route(handle.db, jnp.asarray(idx), jnp.asarray(vals),
+                               fdr=srv.fdr, valid=valid,
+                               num_decoys=handle.num_decoys)
+        with trace.span("serve.finalize.results"):
+            t_done = srv._clock()
+            live: list[Request] = []
+            for i, r in enumerate(handle.reqs):
+                if r.cancelled:
+                    continue
+                r.result = QueryResult(
+                    indices=routed.indices[i], scores=routed.scores[i],
+                    is_target=bool(routed.is_target[i]),
+                    accept=bool(routed.accept[i]), match=int(routed.match[i]),
+                    has_candidate=(True if routed.valid is None
+                                   else bool(routed.valid[i])))
+                r.t_done = t_done
+                live.append(r)
+            if live:
+                srv.stats.record_batch(live)
+                srv.tenant_stats.setdefault(
+                    handle.tenant, LatencyStats()).record_batch(live)
         return live
 
 
@@ -1399,8 +1421,7 @@ class DBSearchServer:
         self._cluster_requests = 0
         self.executor = SearchExecutor(self) if executor is None else executor
         self.scheduler = (ContinuousScheduler(self.queue, self.executor,
-                                              num_slots=num_slots,
-                                              clock=clock)
+                                              num_slots=num_slots)
                           if continuous else None)
 
     def submit(self, query_hv, tenant: str = "default",

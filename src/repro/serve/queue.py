@@ -56,7 +56,10 @@ class Request:
     ``latency_s`` always includes the time spent waiting in the queue;
     ``t_dispatch`` is stamped when the request leaves the queue for the
     device (flush-sync flush, or continuous-batching slot admission),
-    splitting the total into ``queue_wait_s`` + ``service_s``.
+    splitting the total into ``queue_wait_s`` + ``service_s``. ``batch``
+    is the dispatched batch's number, the ``batch`` attribute of its
+    ``serve.dispatch`` and ``serve.finalize`` spans
+    (:mod:`repro.serve.trace`).
     """
 
     rid: int
@@ -69,6 +72,7 @@ class Request:
     t_dispatch: float | None = None  # left the queue for the device
     cancelled: bool = False          # dropped by the scheduler's cancel()
     kind: str = "search"             # request type: "search" | "cluster"
+    batch: int | None = None         # number of the batch that carried it
 
     @property
     def latency_s(self) -> float:

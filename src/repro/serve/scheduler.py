@@ -14,9 +14,10 @@ around two injectable seams so every scheduling decision is
 deterministically unit-testable (the seams are as much the deliverable
 as the scheduler — see ``tests/test_scheduler.py``):
 
-  * **time** — the ``clock`` callable (shared with
-    :class:`~repro.serve.queue.MicroBatchQueue`), so admission order,
-    fairness, and latency accounting run against a fake clock in tests;
+  * **time** — the queue's ``clock`` callable
+    (:class:`~repro.serve.queue.MicroBatchQueue`; the scheduler reads no
+    clock itself), so admission order, fairness, and latency accounting
+    run against a fake clock in tests;
   * **device dispatch** — an *executor* object with three methods::
 
         dispatch(reqs) -> handle   # assemble + launch, stamp t_dispatch;
@@ -58,8 +59,7 @@ either way, which is exactly what the tests pin.
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Any, Callable
+from typing import Any
 
 from repro.serve.queue import MicroBatchQueue, Request
 
@@ -71,7 +71,6 @@ class Slot:
     sid: int
     reqs: list[Request]
     handle: Any
-    t_dispatch: float
 
 
 class ContinuousScheduler:
@@ -85,14 +84,12 @@ class ContinuousScheduler:
     """
 
     def __init__(self, queue: MicroBatchQueue, executor, *,
-                 num_slots: int = 2,
-                 clock: Callable[[], float] = time.monotonic):
+                 num_slots: int = 2):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         self.queue = queue
         self.executor = executor
         self.num_slots = int(num_slots)
-        self._clock = clock
         self._slots: dict[int, Slot] = {}
         self._next_sid = 0
         self.dispatched_batches = 0
@@ -140,8 +137,7 @@ class ContinuousScheduler:
             if not reqs:
                 break
             handle = self.executor.dispatch(reqs)
-            slot = Slot(sid=self._next_sid, reqs=reqs, handle=handle,
-                        t_dispatch=self._clock())
+            slot = Slot(sid=self._next_sid, reqs=reqs, handle=handle)
             self._next_sid += 1
             self._slots[slot.sid] = slot
             self.dispatched_batches += 1

@@ -4,10 +4,12 @@ Nothing runs: each test compiles for a described (not attached) v5e chip,
 which refuses what interpret mode accepts — lane slices Mosaic cannot prove
 128-aligned, kernels that overflow scoped VMEM, programs that overflow HBM.
 Kernels are compiled with ``interpret=False`` and must appear as a Mosaic
-``tpu_custom_call``; every program must fit one chip's 16 GiB.
+``tpu_custom_call``, the search kernels under their own names (what a
+profiler trace shows); every program must fit one chip's 16 GiB.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +64,12 @@ def _compile(fn, sharding, *shapes):
     return compiled.as_text(), mem
 
 
+def _named(text: str, kernel: str) -> bool:
+    """The program holds a Mosaic kernel named ``kernel``."""
+    return re.search(rf'%{kernel}(\.\d+)? = .*"tpu_custom_call"',
+                     text) is not None
+
+
 @pytest.mark.parametrize("dim", [8192, 2048])
 def test_packed_topk_compiles(one_chip, dim):
     w = dim // 32
@@ -69,6 +77,7 @@ def test_packed_topk_compiles(one_chip, dim):
         lambda q, r: topk_hamming_pallas(q, r, dim=dim, k=K, interpret=False),
         one_chip, ((Q, w), jnp.uint32), ((BANK_ROWS, w), jnp.uint32))
     assert "tpu_custom_call" in text
+    assert _named(text, "topk_hamming")
     if dim == 8192:  # lane-aligned words: the bank is read in place
         assert mem.temp_size_in_bytes < 2**20
 
@@ -79,6 +88,7 @@ def test_int8_topk_compiles(one_chip):
         lambda q, r: topk_hamming_pallas(q, r, dim=dim, k=K, interpret=False),
         one_chip, ((Q, dim), jnp.int8), ((262_144, dim), jnp.int8))
     assert "tpu_custom_call" in text
+    assert _named(text, "topk_hamming")
 
 
 @pytest.mark.parametrize("dim", [8192, 2048])
@@ -91,6 +101,7 @@ def test_banded_topk_compiles(one_chip, dim):
         one_chip, ((Q, w), jnp.uint32), ((BANK_ROWS, w), jnp.uint32),
         ((Q,), jnp.int32), ((Q,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert _named(text, "topk_hamming_banded")
 
 
 @pytest.mark.parametrize("dim", [8192, 2048])
@@ -112,6 +123,7 @@ def test_packed_encode_search_compiles(one_chip, dim, block_q):
         one_chip, ((Q, F), jnp.int32), ((F, dim), jnp.int8),
         ((LEVELS, dim), jnp.int8), ((BANK_ROWS, w), jnp.uint32))
     assert "tpu_custom_call" in text
+    assert _named(text, "encode_search")
 
 
 def test_int8_encode_search_compiles(one_chip):
@@ -122,6 +134,7 @@ def test_int8_encode_search_compiles(one_chip):
         one_chip, ((Q, F), jnp.int32), ((F, dim), jnp.int8),
         ((LEVELS, dim), jnp.int8), ((65_536, dim), jnp.int8))
     assert "tpu_custom_call" in text
+    assert _named(text, "encode_search")
 
 
 def test_banded_encode_search_compiles(one_chip):
@@ -134,6 +147,7 @@ def test_banded_encode_search_compiles(one_chip):
         ((LEVELS, dim), jnp.int8), ((BANK_ROWS, dim // 32), jnp.uint32),
         ((Q,), jnp.int32), ((Q,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert _named(text, "encode_search_banded")
 
 
 def test_hd_encode_compiles(one_chip):
