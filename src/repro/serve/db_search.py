@@ -251,7 +251,9 @@ class ShardedDatabase:
     is stored sorted by precursor mass and ``oms.perm`` maps sorted rows
     back to original block rows — search results from the OMS routes are
     translated before they leave :func:`oms_search_encoded`, so callers
-    always see original row numbering.
+    always see original row numbering. ``oms_perm`` is that permutation
+    on the device (replicated over the mesh), kept from the build's
+    sorting gather so no search uploads it again.
     """
 
     data: jax.Array
@@ -265,6 +267,7 @@ class ShardedDatabase:
     emulated_shards: int = 1
     fused: bool = False
     oms: PrecursorIndex | None = None
+    oms_perm: jax.Array | None = None
     # explicit per-bank kernel tile overrides for the fused paths; None
     # defers to the active tuning table / defaults at trace time
     block_q: int | None = None
@@ -364,7 +367,7 @@ def shard_database(refs: jax.Array, *, decoys: jax.Array | None = None,
     del blocks
     num_rows = int(store.shape[0])
 
-    oms_index = None
+    oms_index = oms_perm = None
     if precursor is not None:
         prec = np.asarray(precursor, np.float32).reshape(-1)
         if prec.shape[0] != int(refs.shape[0]):
@@ -380,7 +383,8 @@ def shard_database(refs: jax.Array, *, decoys: jax.Array | None = None,
                     f"decoy_precursor has {dprec.shape[0]} entries for "
                     f"{num_decoys} decoys")
         oms_index = build_precursor_index(prec, dprec)
-        store = store[jnp.asarray(oms_index.perm)]
+        oms_perm = jnp.asarray(oms_index.perm)
+        store = store[oms_perm]
 
     mesh_n = mesh.shape[axis] if (mesh is not None and axis in mesh.shape) else 1
     emu = int(emulate_shards or 1)
@@ -398,12 +402,14 @@ def shard_database(refs: jax.Array, *, decoys: jax.Array | None = None,
         store = jnp.pad(store, ((0, pad_rows), (0, 0)))
     if mesh_n > 1:
         store = jax.device_put(store, NamedSharding(mesh, P(axis, None)))
+        if oms_perm is not None:
+            oms_perm = jax.device_put(oms_perm, NamedSharding(mesh, P()))
     return ShardedDatabase(data=store, num_rows=num_rows, num_decoys=num_decoys,
                            dim=dim, shard_rows=shard_rows, packed=packed,
                            mesh=mesh if mesh_n > 1 else None, axis=axis,
                            emulated_shards=emu if mesh_n == 1 else 1,
                            fused=bool(fused), oms=oms_index,
-                           block_q=block_q, block_r=block_r,
+                           oms_perm=oms_perm, block_q=block_q, block_r=block_r,
                            word_chunk=word_chunk)
 
 
@@ -619,12 +625,14 @@ def _oms_search_inner(db: ShardedDatabase, q_enc: jax.Array, plan: OMSPlan,
 def _oms_finish(db: ShardedDatabase, idx, vals, starts, ends):
     """Shared OMS tail: overflow slots -> the oracle's ascending masked
     rows, then translate every (now in-range) sorted row back to its
-    original bank row."""
+    original bank row through the device-resident ``db.oms_perm``. Run
+    eagerly by the OMS routes and traced into the served batch program
+    (:func:`_oms_batch_program`)."""
     from repro.kernels.topk_hamming import canonicalize_overflow_slots
     s_c = jnp.clip(starts, 0, db.num_rows)
     e_c = jnp.clip(ends, s_c, db.num_rows)
     idx = canonicalize_overflow_slots(idx, vals, s_c, e_c, db.num_rows)
-    idx = jnp.take(jnp.asarray(db.oms.perm), idx, axis=0)
+    idx = jnp.take(db.oms_perm, idx, axis=0)
     return idx, vals
 
 
@@ -874,8 +882,17 @@ def oms_search_levels(db: ShardedDatabase, enc: QueryEncoder,
     _check_k(db, k)
     starts = jnp.asarray(plan.starts, jnp.int32)
     ends = starts + jnp.asarray(plan.lens, jnp.int32)
-    nt = int(plan.num_tiles)
+    idx, vals = _oms_e2e_inner(db, enc, levels, starts, ends,
+                               int(plan.num_tiles), k)
+    return _oms_finish(db, idx, vals, starts, ends)
 
+
+def _oms_e2e_inner(db: ShardedDatabase, enc: QueryEncoder, levels, starts,
+                   ends, nt: int, k: int) -> tuple[jax.Array, jax.Array]:
+    """The routed fused-e2e banded search before the shared tail: top-k
+    (sorted-layout idx, vals) with kernel overflow fillers in place, over
+    a single device, emulated shards or the mesh. ``db.data`` may be a
+    tracer (the served batch program)."""
     if db.mesh is None:
         if db.emulated_shards > 1:
             vals_blocks, idx_blocks = [], []
@@ -900,7 +917,60 @@ def oms_search_levels(db: ShardedDatabase, enc: QueryEncoder,
                                  int(starts.shape[0]), nt)
         idx, vals = fn(levels, enc.id_hvs, enc.level_hvs, starts, ends,
                        db.data)
-    return _oms_finish(db, idx, vals, starts, ends)
+    return idx, vals
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("geometry", "k", "num_tiles", "fdr"))
+def _oms_batch_program(levels, id_hvs, level_hvs, starts, lens, data, perm,
+                       inv, has_candidate, n, *, geometry: tuple, k: int,
+                       num_tiles: int, fdr: float):
+    """One served OMS batch as one device program: the fused-e2e banded
+    search (:func:`_oms_e2e_inner`), the shared tail (:func:`_oms_finish`),
+    the unsort into submit order, and the FDR decision
+    (:func:`_fdr_decide`).
+
+    levels/starts/lens/has_candidate are the bucket-padded batch in
+    precursor-sorted order; ``inv`` (bucket,) unsorts it, pad rows
+    mapping to themselves at the end. ``n`` (traced) is the real row
+    count: rows at or past it are invalid for FDR, which then accepts
+    exactly the set FDR over the first ``n`` rows accepts (an invalid row
+    only repeats its predecessor's running FDR and is never accepted), so
+    a new batch size compiles nothing. FDR runs in submit order, as the
+    eager route does: under score ties the accepted set depends on order.
+    ``geometry`` is :func:`_geometry` of the bank.
+
+    Returns (indices, scores, is_target, accept, match) in submit order.
+    """
+    db = ShardedDatabase(data=data, oms_perm=perm, **dict(geometry))
+    enc = QueryEncoder(id_hvs=id_hvs, level_hvs=level_hvs)
+    ends = starts + lens
+    idx, vals = _oms_e2e_inner(db, enc, levels, starts, ends, num_tiles, k)
+    idx, vals = _oms_finish(db, idx, vals, starts, ends)
+    idx, vals = idx[inv], vals[inv]
+    valid = has_candidate[inv] & (jnp.arange(inv.shape[0]) < n)
+    return (idx, vals) + _fdr_decide(idx, vals, db.num_decoys, fdr, valid)
+
+
+def _oms_batch(db: ShardedDatabase, enc: QueryEncoder, levels, plan: OMSPlan,
+               k: int, *, inv: np.ndarray, n: int, fdr: float):
+    """Launch :func:`_oms_batch_program` for one served batch: ``levels``
+    and ``plan`` bucket-padded in precursor-sorted order, ``inv`` the
+    padded unsort permutation, ``n`` the real rows."""
+    _check_levels(db, enc, levels)
+    _check_k(db, k)
+    return _oms_batch_program(
+        levels, enc.id_hvs, enc.level_hvs, plan.starts, plan.lens, db.data,
+        db.oms_perm, inv.astype(np.int32), plan.has_candidate, np.int32(n),
+        geometry=_geometry(db), k=k, num_tiles=int(plan.num_tiles), fdr=fdr)
+
+
+def _geometry(db: ShardedDatabase) -> tuple:
+    """The bank's static fields as a hashable jit key: everything but
+    the arrays (``data``, ``oms_perm``) and the host-side index."""
+    return tuple((f.name, getattr(db, f.name))
+                 for f in dataclasses.fields(db)
+                 if f.name not in ("data", "oms", "oms_perm"))
 
 
 def sharded_topk_search(queries: jax.Array, refs: jax.Array, k: int, *,
@@ -974,19 +1044,27 @@ def fdr_route(db: ShardedDatabase, indices: jax.Array, scores: jax.Array,
     (:mod:`repro.serve.delta`), where the decoy block spans both sides.
     """
     nd = db.num_decoys if num_decoys is None else int(num_decoys)
-    top_idx = indices[:, 0]
-    top_val = scores[:, 0]
-    is_target = top_idx >= nd
-    accept = fdr_filter(top_val.astype(jnp.float32), is_target, fdr=fdr,
-                        valid=valid)
-    if valid is not None:
-        is_target = is_target & valid
-    match = jnp.where(accept & is_target, top_idx - nd, -1)
+    is_target, accept, match = _fdr_decide(indices, scores, nd, fdr, valid)
     return FDRSearchResult(
         indices=np.asarray(indices), scores=np.asarray(scores),
         is_target=np.asarray(is_target), accept=np.asarray(accept),
         match=np.asarray(match),
         valid=None if valid is None else np.asarray(valid))
+
+
+def _fdr_decide(indices, scores, num_decoys: int, fdr: float, valid):
+    """:func:`fdr_route`'s arithmetic on device arrays, shared by the
+    eager route and the served batch program: (is_target, accept,
+    match)."""
+    top_idx = indices[:, 0]
+    top_val = scores[:, 0]
+    is_target = top_idx >= num_decoys
+    accept = fdr_filter(top_val.astype(jnp.float32), is_target, fdr=fdr,
+                        valid=valid)
+    if valid is not None:
+        is_target = is_target & valid
+    match = jnp.where(accept & is_target, top_idx - num_decoys, -1)
+    return is_target, accept, match
 
 
 def search_with_fdr(db: ShardedDatabase, queries: jax.Array, k: int,
@@ -1062,6 +1140,9 @@ class BatchHandle:
     inv: np.ndarray | None = None    # OMS unsort permutation
     oms: bool = False
     num_decoys: int | None = None    # merged-row-space override (delta path)
+    # the one-program OMS route: (is_target, accept, match) on the device,
+    # with idx/vals already unsorted into submit order (inv is then None)
+    routed: tuple[jax.Array, jax.Array, jax.Array] | None = None
 
 
 @dataclasses.dataclass
@@ -1103,7 +1184,10 @@ class SearchExecutor:
         (``Array.is_ready``; conservatively True on runtimes without it);
       * ``finalize`` blocks on the device values, unsorts OMS batches,
         routes FDR, fills per-request results, stamps ``t_done``, records
-        latency stats, and drops cancelled requests.
+        latency stats, and drops cancelled requests. A fused-e2e OMS
+        batch with no delta was unsorted and FDR-routed on the device, in
+        the same program as its search, so finalize only copies it to
+        the host (see ``_dispatch_oms``).
 
     Each call is one span of :mod:`repro.serve.trace` (``serve.dispatch``,
     ``serve.finalize``, keyed by the batch number and first request id),
@@ -1168,11 +1252,17 @@ class SearchExecutor:
         """OMS dispatch: precursor-sort the batch (nearby masses share
         kernel tiles, keeping the static tile budget small — pad rows
         inherit the highest real precursor), plan host-side, launch the
-        banded search. Results unsort at finalize; FDR routing is
-        order-independent. With a non-empty delta the plan and search run
-        merged over base + delta (see :mod:`repro.serve.delta`) — the
-        fused-e2e shortcut falls back to the staged pipeline for those
-        batches, which is bit-identical."""
+        banded search.
+
+        The fused-e2e route with no delta launches one device program
+        (:func:`_oms_batch_program`): search, OMS tail, unsort and FDR.
+        No other device op runs between this launch and finalize's copy
+        to the host, so the host's work on the next batch overlaps this
+        batch's kernel. Every other route unsorts at finalize and routes
+        FDR there. With a non-empty delta the plan and search run merged
+        over base + delta (see :mod:`repro.serve.delta`) — the fused-e2e
+        shortcut falls back to the staged pipeline for those batches,
+        which is bit-identical."""
         srv = self.server
         num_decoys = None
         with trace.span("serve.dispatch.plan") as attrs:
@@ -1189,32 +1279,38 @@ class SearchExecutor:
             else:
                 plan = oms_plan(db, prec_padded, srv.oms)
                 attrs["tiles"] = int(plan.num_tiles)
+        single = delta is None and srv.fused_e2e
         with trace.span("serve.dispatch.assemble"):
-            if delta is not None:
+            if single:
+                inputs = (srv._levels_batch(reqs, bucket),)
+                search = functools.partial(
+                    _oms_batch, db, srv.encoder, fdr=srv.fdr, n=n,
+                    inv=np.concatenate([inv, np.arange(n, bucket)]))
+            elif delta is not None:
                 from repro.serve.delta import merged_oms_search_encoded
                 inputs = (srv._encode_batch(reqs, db, bucket, tenant),
                           srv._raw_batch(reqs, bucket))
                 search = functools.partial(merged_oms_search_encoded, db,
                                            delta)
                 num_decoys = db.num_decoys + delta.num_decoys
-            elif srv.encoder is not None and srv.fused_e2e:
-                inputs = (srv._levels_batch(reqs, bucket),)
-                search = functools.partial(oms_search_levels, db,
-                                           srv.encoder, fused_e2e=True)
             else:
                 inputs = (srv._encode_batch(reqs, db, bucket, tenant),)
                 search = functools.partial(oms_search_encoded, db)
             inputs = [np.concatenate([b[:n][order], b[n:]]) for b in inputs]
         with trace.span("serve.dispatch.launch"):
-            idx, vals = search(*map(jax.device_put, inputs), plan, srv.k)
+            idx, vals, *routed = search(*map(jax.device_put, inputs), plan,
+                                        srv.k)
         valid = plan.has_candidate[:n][inv]
         srv._oms_batches += 1
+        srv._oms_single_launch += single
         srv._oms_cand_frac += plan.candidate_fraction
         srv._oms_scan_frac += plan.scanned_fraction
         srv._oms_no_candidate += int((~valid).sum())
         return BatchHandle(reqs=reqs, tenant=tenant, db=db, n=n, idx=idx,
-                           vals=vals, valid=valid, inv=inv, oms=True,
-                           num_decoys=num_decoys)
+                           vals=vals, valid=valid,
+                           inv=None if single else inv, oms=True,
+                           num_decoys=num_decoys,
+                           routed=tuple(routed) if single else None)
 
     def _dispatch_cluster(self, reqs: list[Request], tenant: str,
                           bucket: int) -> ClusterBatchHandle:
@@ -1275,15 +1371,21 @@ class SearchExecutor:
         srv = self.server
         n = handle.n
         with trace.span("serve.finalize.wait"):
-            idx = np.asarray(handle.idx)[:n]  # blocks until the device is done
-            vals = np.asarray(handle.vals)[:n]
+            # one copy to the host; blocks until the device is done
+            idx, vals, *decided = (a[:n] for a in jax.device_get(
+                (handle.idx, handle.vals, *(handle.routed or ()))))
         with trace.span("serve.finalize.fdr"):
-            if handle.inv is not None:
-                idx, vals = idx[handle.inv], vals[handle.inv]
-            valid = None if handle.valid is None else jnp.asarray(handle.valid)
-            routed = fdr_route(handle.db, jnp.asarray(idx), jnp.asarray(vals),
-                               fdr=srv.fdr, valid=valid,
-                               num_decoys=handle.num_decoys)
+            if decided:
+                routed = FDRSearchResult(idx, vals, *decided,
+                                         valid=handle.valid)
+            else:
+                if handle.inv is not None:
+                    idx, vals = idx[handle.inv], vals[handle.inv]
+                valid = (None if handle.valid is None
+                         else jnp.asarray(handle.valid))
+                routed = fdr_route(handle.db, jnp.asarray(idx),
+                                   jnp.asarray(vals), fdr=srv.fdr,
+                                   valid=valid, num_decoys=handle.num_decoys)
         with trace.span("serve.finalize.results"):
             t_done = srv._clock()
             live: list[Request] = []
@@ -1405,6 +1507,7 @@ class DBSearchServer:
         self._clock = clock
         self.oms = oms
         self._oms_batches = 0
+        self._oms_single_launch = 0
         self._oms_cand_frac = 0.0
         self._oms_scan_frac = 0.0
         self._oms_no_candidate = 0
@@ -1644,6 +1747,7 @@ class DBSearchServer:
                 "open_tol": self.oms.open_tol,
                 "open_search": self.oms.open_search,
                 "batches": self._oms_batches,
+                "single_launch_batches": self._oms_single_launch,
                 "candidate_fraction": self._oms_cand_frac / nb,
                 "scanned_fraction": self._oms_scan_frac / nb,
                 "no_candidate": self._oms_no_candidate,

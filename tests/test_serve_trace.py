@@ -151,11 +151,67 @@ def test_compiles_are_counted_by_the_open_span(server):
     before = trace.compiles()
     _serve(server, 5, seed=3)
     first = trace.compiles()
-    assert first.get("serve.finalize.fdr", 0) > before.get(
-        "serve.finalize.fdr", 0)
-    _serve(server, 5, seed=4)  # the same size again: FDR compiles nothing
+    # the batch's one program compiles at its launch
+    assert first.get("serve.dispatch.launch", 0) > before.get(
+        "serve.dispatch.launch", 0)
+    _serve(server, 5, seed=4)  # the same size again compiles nothing
     again = trace.compiles()
-    assert again.get("serve.finalize.fdr", 0) == first["serve.finalize.fdr"]
+    assert {k: v for k, v in again.items() if k} == {
+        k: v for k, v in first.items() if k}
     # a compile outside every span is counted under None
     jax.jit(lambda x: x * 3 + 1)(jnp.arange(11))
     assert trace.compiles().get(None, 0) > again.get(None, 0)
+
+
+def _oms_server(bank, *, max_batch: int) -> DBSearchServer:
+    enc = QueryEncoder.from_config(dim=D, num_features=F, num_levels=M,
+                                   seed=7)
+    return DBSearchServer(bank, k=K, max_batch_size=max_batch,
+                          continuous=True,
+                          oms=OMSConfig(tol=40, open_tol=250), encoder=enc,
+                          fused_e2e=True)
+
+
+def _bank_rows(seed: int, rows: int):
+    rng = np.random.default_rng(seed)
+    hvs = [jnp.asarray(rng.choice([-1, 1], size=(rows, D)).astype(np.int8))
+           for _ in range(2)]
+    return hvs[0], hvs[1], rng.uniform(100, 900, rows).astype(np.float32)
+
+
+def test_new_batch_sizes_in_one_bucket_compile_nothing():
+    """Real sizes 3, 17 and 100 share the 128 bucket: after the first
+    batch no stage of the executor compiles, and every batch took the
+    one-program route. The bank fits one kernel tile, so the tile budget
+    (part of the program's key) is the same for every batch."""
+    refs, decoys, prec = _bank_rows(11, 60)
+    server = _oms_server(shard_database(refs, decoys=decoys, precursor=prec,
+                                        fused=True), max_batch=128)
+    jax.clear_caches()
+    _serve(server, 3, seed=5)
+    first = trace.compiles()
+    for n, seed in ((17, 6), (100, 7)):
+        _serve(server, n, seed=seed)
+    after = trace.compiles()
+    assert {k: after.get(k, 0) - first.get(k, 0) for k in after
+            if k and k.startswith(("serve.dispatch", "serve.finalize"))
+            and after.get(k, 0) != first.get(k, 0)} == {}
+    oms = server.summary()["oms"]
+    assert oms["batches"] == 3 and oms["single_launch_batches"] == 3
+    assert server.summary()["buckets"] == {128: 3}
+
+
+def test_a_delta_tenant_takes_the_merged_route():
+    """Batches against a bank with appended rows search base + delta
+    merged (staged), so none of them counts as a one-program batch."""
+    from repro.serve import BankRegistry
+    refs, decoys, prec = _bank_rows(12, 40)
+    reg = BankRegistry(fused=True)
+    reg.register("default", refs, decoys=decoys, precursor=prec)
+    server = _oms_server(reg, max_batch=BATCH)
+    more, more_decoys, more_prec = _bank_rows(13, 4)
+    server.append("default", more, more_decoys, precursor=more_prec)
+    done = _serve(server, 10, seed=8)
+    oms = server.summary()["oms"]
+    assert len(done) == 10 and oms["batches"] == 2
+    assert oms["single_launch_batches"] == 0

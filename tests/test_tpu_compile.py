@@ -170,3 +170,29 @@ def test_bank_encoder_chunk_compiles(one_chip):
         ((LEVELS, dim), jnp.int8))
     assert mem.output_size_in_bytes == rows * dim // 8
     assert mem.temp_size_in_bytes < 2**30
+
+
+def test_served_oms_batch_program_compiles(one_chip, monkeypatch):
+    """The served OMS batch as one program at the HEK293 cell's size
+    (4,489,008 packed rows, a 2,048-tile budget, two bands): the banded
+    kernel, the overflow tail, the permutation gather, the unsort and FDR
+    compile together and fit one chip."""
+    from repro.kernels.encode_search import ops
+    from repro.serve import db_search
+    # the program picks interpret mode from the backend, which is the CPU
+    # here: compile the Mosaic kernel the chip runs instead
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    dim, rows = 8192, 4_489_008
+    geometry = db_search._geometry(db_search.ShardedDatabase(
+        data=None, num_rows=rows, num_decoys=rows // 2, dim=dim,
+        shard_rows=rows, packed=True, mesh=None, axis="model", fused=True))
+    text, _ = _compile(
+        lambda lv, ids, lvs, s, n_, r, perm, inv, hc, n:
+        db_search._oms_batch_program(
+            lv, ids, lvs, s, n_, r, perm, inv, hc, n, geometry=geometry,
+            k=K, num_tiles=2048, fdr=0.01),
+        one_chip, ((Q, F), jnp.int32), ((F, dim), jnp.int8),
+        ((LEVELS, dim), jnp.int8), ((2, Q), jnp.int32), ((2, Q), jnp.int32),
+        ((rows, dim // 32), jnp.uint32), ((rows,), jnp.int32),
+        ((Q,), jnp.int32), ((Q,), jnp.bool_), ((), jnp.int32))
+    assert _named(text, "encode_search_banded")
