@@ -120,6 +120,11 @@ class Deployment:
     def queued(self) -> int:
         return len(self.server.queue)
 
+    @property
+    def buckets(self) -> tuple[int, ...]:
+        """The batch sizes the server pads a batch up to."""
+        return self.server.buckets
+
     def oms_counters(self) -> dict:
         """Sums over all batches so far of the planner's candidate and
         scanned fractions (the server reports their means)."""

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 CHUNK = 8192  # rows per generator/encoder step on the device
+SET_TAG = 12  # stream of a traffic's ``set_seed``: its random batch set
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,9 +100,11 @@ def codebooks(key, dim: int, num_bins: int, num_levels: int):
     return id_hvs, level_hvs, base
 
 
-def _templates(key, spec: Spec):
-    """Peak positions, intensities and precursor of every template."""
-    kp, ki, km = jax.random.split(key, 3)
+def _templates(key, prec_key, spec: Spec):
+    """Peak positions and intensities of every template from ``key``, its
+    precursor from ``prec_key``."""
+    kp, ki, _ = jax.random.split(key, 3)
+    km = jax.random.split(prec_key, 3)[2]
     shape = (spec.templates, spec.peaks_per_template)
     pos = jax.random.randint(kp, shape, 0, spec.num_bins, jnp.int32)
     inten = jax.random.uniform(ki, shape, minval=0.2, maxval=1.0)
@@ -127,24 +130,30 @@ def _replicate(key, tpos, tint, spec: Spec):
             jnp.concatenate([inten, nint], axis=1))
 
 
-def _modify(key, pos, inten, prec, spec: Spec):
-    """Open modifications: a share of the spectra move the upper half of
-    the m/z axis up by a few bins (peaks pushed past the last bin are lost;
-    peaks just below the middle gain a copy above it) and gain precursor
-    mass."""
-    n = pos.shape[0]
+def _mod_draws(key, n: int, spec: Spec):
+    """Which of ``n`` spectra are modified, by how many bins and by what
+    precursor mass (see :func:`_modify`)."""
     km, kd, ks = jax.random.split(key, 3)
     is_mod = jax.random.uniform(km, (n,)) < spec.modification_rate
     b_lo, b_hi = spec.modification_bins
     delta = jax.random.randint(kd, (n,), b_lo, b_hi, jnp.int32)
+    m_lo, m_hi = spec.modification_mass
+    shift = jax.random.uniform(ks, (n,), minval=m_lo, maxval=m_hi)
+    return is_mod, delta, shift
+
+
+def _modify(draws, pos, inten, prec, spec: Spec):
+    """Open modifications: a share of the spectra move the upper half of
+    the m/z axis up by a few bins (peaks pushed past the last bin are lost;
+    peaks just below the middle gain a copy above it) and gain precursor
+    mass."""
+    is_mod, delta, shift = draws
     half = spec.num_bins // 2
     mod = is_mod[:, None]
     stay = jnp.where(mod & (pos >= half), -1, pos)
     moved = pos + delta[:, None]
     moved = jnp.where(mod & (moved >= half) & (moved < spec.num_bins),
                       moved, -1)
-    m_lo, m_hi = spec.modification_mass
-    shift = jax.random.uniform(ks, (n,), minval=m_lo, maxval=m_hi)
     return (jnp.concatenate([stay, moved], axis=1),
             jnp.concatenate([inten, inten], axis=1),
             jnp.where(is_mod, prec + shift, prec))
@@ -219,28 +228,33 @@ def encode_pair(levels, id_hvs, base, num_levels: int):
     return out[0], out[1]
 
 
-def _padded_templates(key, spec: Spec):
-    pos, inten, prec = _templates(key, spec)
+def _padded_templates(key, prec_key, spec: Spec):
+    pos, inten, prec = _templates(key, prec_key, spec)
     pad = -spec.templates % CHUNK
     return (jnp.pad(pos, ((0, pad), (0, 0))),
             jnp.pad(inten, ((0, pad), (0, 0))), jnp.pad(prec, (0, pad)))
 
 
 @functools.partial(jax.jit, static_argnums=0)
-def template_table(spec: Spec, key):
-    return _padded_templates(key, spec)
+def template_table(spec: Spec, key, prec_key=None):
+    return _padded_templates(key, key if prec_key is None else prec_key,
+                             spec)
 
 
 @functools.partial(jax.jit, static_argnums=0)
-def library_pass(spec: Spec, key, tpos, tint, tprec, id_hvs, base):
+def library_pass(spec: Spec, key, prec_key, tpos, tint, tprec, id_hvs,
+                 base):
     """One replicate of every template, encoded: (targets, decoys) packed
     (chunks, CHUNK, W), precursors (chunks, CHUNK), and per chunk one
-    seeded row with its levels, for the bank encoder's check."""
+    seeded row with its levels, for the bank encoder's check. The
+    precursors' measurement noise comes from ``prec_key``, the rest from
+    ``key``."""
     chunks = tpos.shape[0] // CHUNK
 
     def chunk(c):
         kc = jax.random.fold_in(key, c)
-        k_rep, k_prec, k_row = jax.random.split(kc, 3)
+        k_rep, _, k_row = jax.random.split(kc, 3)
+        k_prec = jax.random.split(jax.random.fold_in(prec_key, c), 3)[1]
         lo = c * CHUNK
         tp = jax.lax.dynamic_slice_in_dim(tpos, lo, CHUNK)
         ti = jax.lax.dynamic_slice_in_dim(tint, lo, CHUNK)
@@ -254,6 +268,24 @@ def library_pass(spec: Spec, key, tpos, tint, tprec, id_hvs, base):
         return tgt, dec, prec, lo + row, levels[row]
 
     return jax.lax.map(chunk, jnp.arange(chunks))
+
+
+def _queries(spec: Spec, kc, ids, tpos, tint, tprec, draws=None,
+             noise=None):
+    """Fresh replicates of templates ``ids`` with the configuration's
+    modification mix, from chunk key ``kc``: levels (uint8), precursors.
+    ``draws`` gives the modifications instead (see :func:`_mod_draws`),
+    ``noise`` the precursors' measurement noise."""
+    _, k_rep, k_prec, k_mod, _ = jax.random.split(kc, 5)
+    pos, inten = _replicate(k_rep, tpos[ids], tint[ids], spec)
+    if noise is None:
+        noise = spec.precursor_noise * jax.random.normal(k_prec, (CHUNK,))
+    prec = tprec[ids] + noise
+    if draws is None:
+        draws = _mod_draws(k_mod, CHUNK, spec)
+    pos, inten, prec = _modify(draws, pos, inten, prec, spec)
+    levels = peaks_to_levels(pos, inten, spec.num_bins, spec.num_levels)
+    return levels.astype(jnp.uint8), prec
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
@@ -272,7 +304,7 @@ def query_chunks(spec: Spec, chunks: int, strata: int, key, tpos, tint,
 
     def chunk(c):
         kc = jax.random.fold_in(key, c)
-        k_id, k_rep, k_prec, k_mod, k_run = jax.random.split(kc, 5)
+        k_id, _, _, _, k_run = jax.random.split(kc, 5)
         slot = jnp.arange(CHUNK, dtype=jnp.int32) % strata
         pick = slot * t // strata + jax.random.randint(
             k_id, (CHUNK,), 0, t // strata, jnp.int32)
@@ -280,12 +312,77 @@ def query_chunks(spec: Spec, chunks: int, strata: int, key, tpos, tint,
                                axis=1)
                    + jnp.arange(runs, dtype=jnp.int32)[:, None] * strata)
         ids = ranked[pick[shuffle.reshape(-1)]]
-        pos, inten = _replicate(k_rep, tpos[ids], tint[ids], spec)
-        prec = tprec[ids] + spec.precursor_noise * jax.random.normal(
-            k_prec, (CHUNK,))
-        pos, inten, prec = _modify(k_mod, pos, inten, prec, spec)
-        levels = peaks_to_levels(pos, inten, spec.num_bins, spec.num_levels)
-        return levels.astype(jnp.uint8), prec
+        return _queries(spec, kc, ids, tpos, tint, tprec)
+
+    return jax.lax.map(chunk, jnp.arange(chunks))
+
+
+GOLDEN = 0x9E3779B9  # 2**32 / golden ratio, odd
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def query_chunks_golden(spec: Spec, chunks: int, key, tpos, tint, tprec):
+    """As :func:`query_chunks`, but query i takes the template of
+    precursor rank floor(frac(u + i / phi) * templates), a golden-ratio
+    sequence from an offset u drawn from ``key``: any n consecutive
+    queries split the range into gaps of at most three lengths, the
+    largest under 2 / n of it. Whatever n consecutive requests an open
+    loop puts in a batch then span the range alike, so the seed moves
+    the offset and the spectra, not the work."""
+    t = spec.templates
+    ranked = jnp.argsort(tprec[:t]).astype(jnp.int32)
+    k_off, k_chunks = jax.random.split(key)
+    offset = jax.random.bits(k_off, (), jnp.uint32)
+
+    def chunk(c):
+        i = (c * CHUNK + jnp.arange(CHUNK)).astype(jnp.uint32)
+        frac = offset + i * jnp.uint32(GOLDEN)  # mod 2**32
+        rank = ((frac >> 8).astype(jnp.float32) * (t / 2.0 ** 24)).astype(
+            jnp.int32)
+        ids = ranked[jnp.minimum(rank, t - 1)]
+        return _queries(spec, jax.random.fold_in(k_chunks, c), ids, tpos,
+                        tint, tprec)
+
+    return jax.lax.map(chunk, jnp.arange(chunks))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def query_chunks_batch_set(spec: Spec, chunks: int, batch: int,
+                           set_batches: int, key, set_key, tpos, tint,
+                           tprec):
+    """As :func:`query_chunks`, but every query is a member of one set of
+    ``set_batches`` batches of ``batch``, drawn from ``set_key``: each
+    member a precursor rank drawn uniformly (random precursors, as an
+    instrument sends them), its precursor's measurement noise and its
+    modification. Every cycle of ``set_batches * batch`` queries sends each
+    batch of the set once, in an order of the cycle's own drawn from
+    ``set_key``, its members shuffled by ``key``. With the library's
+    precursors drawn from the set's seed too (``build_library``'s
+    ``precursor_seed``), a backlog that dispatches full batches serves the
+    same batches in the same order for every seed: the seed moves the
+    spectra, not the work."""
+    t = spec.templates
+    per = batch * set_batches
+    cycles = -(-chunks * CHUNK // per)
+    ranked = jnp.argsort(tprec[:t]).astype(jnp.int32)
+    k_rank, k_mod = jax.random.split(set_key)
+    rank = jax.random.randint(k_rank, (per,), 0, t, jnp.int32)
+    mods = _mod_draws(k_mod, per, spec)
+    noise = spec.precursor_noise * jax.random.normal(
+        jax.random.fold_in(set_key, 2), (per,))
+    k_order = jax.random.fold_in(set_key, 1)
+    order = jax.vmap(lambda c: jnp.argsort(jax.random.uniform(
+        jax.random.fold_in(k_order, c), (set_batches,))))(jnp.arange(cycles))
+    k_member, k_chunks = jax.random.split(key)
+    shuffle = jnp.argsort(
+        jax.random.uniform(k_member, (cycles, set_batches, batch)), axis=2)
+    member = (order[:, :, None] * batch + shuffle).reshape(-1)
+
+    def chunk(c):
+        m = jax.lax.dynamic_slice_in_dim(member, c * CHUNK, CHUNK)
+        return _queries(spec, jax.random.fold_in(k_chunks, c),
+                        ranked[rank[m]], tpos, tint, tprec,
+                        tuple(d[m] for d in mods), noise[m])
 
     return jax.lax.map(chunk, jnp.arange(chunks))
 
@@ -307,13 +404,18 @@ class Library:
         return int(self.targets.shape[0])
 
 
-def build_library(spec: Spec, seed: int, log=print) -> tuple[Library, tuple]:
+def build_library(spec: Spec, seed: int, log=print,
+                  precursor_seed: int | None = None) -> tuple[Library, tuple]:
     """Generate and encode the library pass by pass on the device, staging
     each pass on the host while the next one runs. Returns the library and
-    the device template table (for the query pool)."""
+    the device template table (for the query pool). ``precursor_seed``, if
+    given, draws every precursor (the templates' and their measurement
+    noise) in place of ``seed``, so that the library's precursor layout is
+    the same for every seed."""
     id_hvs, level_hvs, base = codebooks(seed_key(seed, 1), spec.dim,
                                         spec.num_bins, spec.num_levels)
-    table = template_table(spec, seed_key(seed, 2))
+    p_seed = seed if precursor_seed is None else precursor_seed
+    table = template_table(spec, seed_key(seed, 2), seed_key(p_seed, 2))
     n, t = spec.library_rows, spec.templates
     targets = np.empty((n, spec.words), np.uint32)
     decoys = np.empty((n, spec.words), np.uint32)
@@ -329,10 +431,11 @@ def build_library(spec: Spec, seed: int, log=print) -> tuple[Library, tuple]:
         rows.append(np.asarray(row) + lo)
         levels.append(np.asarray(lv))
 
-    lib_key = seed_key(seed, 3)
+    lib_key, prec_key = seed_key(seed, 3), seed_key(p_seed, 3)
     pending = None
     for p in range(spec.passes):
-        out = library_pass(spec, jax.random.fold_in(lib_key, p), *table,
+        out = library_pass(spec, jax.random.fold_in(lib_key, p),
+                           jax.random.fold_in(prec_key, p), *table,
                            id_hvs, base)
         if pending is not None:
             stage(*pending)
@@ -355,13 +458,29 @@ class QueryPool:
 
 
 def query_pool(spec: Spec, seed: int, tag: int, count: int, table,
-               strata: int = 1) -> QueryPool:
-    """``count`` distinct query spectra of stream ``tag``, stratified by
-    precursor in runs of ``strata`` (see :func:`query_chunks`)."""
-    if CHUNK % strata or spec.templates < strata:
-        raise ValueError(f"{strata} strata do not divide {CHUNK}-row chunks "
-                         f"of {spec.templates} templates")
+               strata: int = 1, order: str = "stratified",
+               set_batches: int = 0, set_seed: int = 0) -> QueryPool:
+    """``count`` distinct query spectra of stream ``tag``, in precursor
+    order ``stratified`` (by runs of ``strata``, see :func:`query_chunks`),
+    ``golden`` (see :func:`query_chunks_golden`) or ``random`` (a set of
+    ``set_batches`` random batches of ``strata`` drawn from ``set_seed``,
+    see :func:`query_chunks_batch_set`)."""
     chunks = max(1, -(-count // CHUNK))
-    lv, pr = query_chunks(spec, chunks, strata, seed_key(seed, tag), *table)
+    key = seed_key(seed, tag)
+    if order == "golden":
+        lv, pr = query_chunks_golden(spec, chunks, key, *table)
+    elif order == "random":
+        if set_batches < 1:
+            raise ValueError("a random order needs set_batches >= 1")
+        lv, pr = query_chunks_batch_set(spec, chunks, strata, set_batches,
+                                        key, seed_key(set_seed, SET_TAG),
+                                        *table)
+    elif order == "stratified":
+        if CHUNK % strata or spec.templates < strata:
+            raise ValueError(f"{strata} strata do not divide {CHUNK}-row "
+                             f"chunks of {spec.templates} templates")
+        lv, pr = query_chunks(spec, chunks, strata, key, *table)
+    else:
+        raise ValueError(f"unknown precursor order {order!r}")
     return QueryPool(levels=np.asarray(lv).reshape(-1, spec.num_bins)[:count],
                      precursor=np.asarray(pr).reshape(-1)[:count])

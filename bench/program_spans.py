@@ -41,3 +41,24 @@ def stage_ms(rec, name: str) -> float | None:
     if len(per_batch) < len(want):
         return None
     return sum(per_batch.values()) / len(want) * 1e3
+
+
+def batch_tiles(rec) -> list[tuple[int, int]] | None:
+    """(requests, OMS tile budget) of each window batch, in dispatch
+    order, from the ``serve.dispatch.plan`` spans; None where any is
+    missing."""
+    try:
+        from repro.serve import trace
+    except ImportError:
+        return None
+    spans = [s for s in trace.spans() if s.start >= rec.t0]
+    by_id = {s.id: s for s in spans}
+    tiles = {}
+    for s in spans:
+        top = by_id.get(s.parent)
+        if s.name == "serve.dispatch.plan" and top is not None:
+            tiles[top.attrs.get("rid0")] = s.attrs.get("tiles")
+    out = [(len(b.rids), tiles.get(b.rids[0])) for b in rec.window_batches]
+    if any(t is None for _, t in out):
+        return None
+    return out

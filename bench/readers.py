@@ -60,3 +60,20 @@ def device_idle(rec):
     if not rec.trace or rec.trace["window_s"] <= 0:
         return None
     return 100.0 * (1.0 - rec.trace["busy_s"] / rec.trace["window_s"])
+
+
+def queue_wait_p95_ms(rec):
+    """95th percentile over the window's answered requests of the wait
+    from when each was due until its batch was dispatched (ms); requests
+    never answered are counted by the run's ``failed``."""
+    return percentile([(rec.served[r].t_dispatch - rec.due[r]) * 1e3
+                       for r in rec.attempted if r in rec.served], 95)
+
+
+def batch_fill(rec):
+    """Mean real requests per window batch over the batch cap."""
+    b = rec.window_batches
+    if not b:
+        return None
+    cap = rec.config["serving"]["max_batch_size"]
+    return sum(len(x.rids) for x in b) / (len(b) * cap)

@@ -26,6 +26,7 @@ import time
 T_START = time.monotonic()
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -79,6 +80,9 @@ def load_reader(root: Path, metric: str):
 
 
 def end_to_end(name: str, rec) -> float:
+    # a metric held to one cell's bound (``spectra_per_s.random``) is
+    # computed as the metric its name starts with
+    name = name.partition(".")[0]
     if name == "setup_s":
         return rec.setup_s
     if name in ("p50_ms", "p95_ms"):
@@ -140,6 +144,101 @@ def compile_cache(root: Path) -> list[int]:
     return _COMPILES
 
 
+def bring_up(cfg: dict, mix: dict, seed: int, pools, compiles):
+    """A cell's set-up: the library from ``seed`` (its precursors from a
+    random order's ``set_seed``), a query pool of ``count`` spectra for
+    each (pool seed, count) of ``pools``, the bank and the server, then the
+    warm-up of every program the mix reaches over those pools' precursors.
+    Returns (spec, library, pools, deployment)."""
+    import jax
+
+    import adapter
+    import gen
+    import loadgen
+
+    spec = gen.Spec.from_config(cfg)
+    serving = cfg["serving"]
+    max_batch = serving["max_batch_size"]
+    device = jax.devices()[0]
+
+    def phase(label, t0):
+        log(f"set-up {label}: {time.monotonic() - t0:.3f} s")
+
+    t = time.monotonic()
+    # a random order's set fixes every precursor: the same work each seed
+    random_order = mix.get("precursor_order") == "random"
+    lib, table = gen.build_library(
+        spec, seed, precursor_seed=mix["set_seed"] if random_order else None)
+    phase(f"library ({spec.library_rows} targets + as many decoys, "
+          f"{2 * spec.library_rows * spec.dim // 8} packed bytes)", t)
+    t = time.monotonic()
+    made = [gen.query_pool(spec, s, QUERY_TAG, count, table, max_batch,
+                           mix.get("precursor_order", "stratified"),
+                           mix.get("set_batches", 0), mix.get("set_seed", 0))
+            for s, count in pools]
+    warm = gen.query_pool(spec, seed, WARM_TAG,
+                          loadgen.warm_pool_size(mix, max_batch), table,
+                          max_batch)
+    del table
+    phase(f"query pool ({sum(map(len, made))} spectra, {len(warm)} for "
+          f"warm-up)", t)
+    t = time.monotonic()
+    dep = adapter.Deployment(lib, serving, cfg["windows"][mix["window"]])
+    phase("bank and server", t)
+    log(f"device 0 peak memory after the bank build: "
+        f"{(device.memory_stats() or {}).get('peak_bytes_in_use', 0)} bytes")
+    t = time.monotonic()
+    batches = loadgen.warm_batches(
+        mix, max_batch, dep.buckets, warm,
+        min(float(p.precursor.min()) for p in made),
+        max(float(p.precursor.max()) for p in made))
+    sweeps = loadgen.warm_up(dep, batches, lambda: compiles[0])
+    phase(f"warm-up ({sweeps} sweeps of {len(batches[0])} batches, "
+          f"{compiles[0]} programs so far)", t)
+    return spec, lib, made, dep
+
+
+def measure(dep, mix: dict, pool, seconds: float, seed: int, compiles,
+            cfg: dict):
+    """One measured window. Returns it and its record: the requests due,
+    those answered so far (the answers still in flight are collected by
+    ``loadgen.drain`` into the same dict), the batches dispatched inside
+    it, the planner's counters as it opened and closed, and the programs
+    compiled or loaded inside it."""
+    import loadgen
+
+    opened = {}
+
+    def on_open():
+        opened.update(counters=dep.oms_counters(), compiles=compiles[0],
+                      batches=len(dep.batches))
+
+    w = loadgen.run(dep, mix, pool, seconds, seed,
+                    cfg["serving"]["max_batch_size"], dep.annotate, on_open)
+    rec = types.SimpleNamespace(
+        t0=w.t0, t_end=w.t_end, due=w.due, attempted=w.attempted,
+        served=w.served,
+        window_batches=[b for b in dep.batches[opened["batches"]:]
+                        if b.t_dispatch < w.t_end],
+        counters=(opened["counters"], dep.oms_counters()),
+        programs=compiles[0] - opened["compiles"], config=cfg, mix=mix)
+    return w, rec
+
+
+def log_batches(rec) -> None:
+    """Log the window's batches by OMS tile budget, and each batch's
+    requests and budget in dispatch order."""
+    import program_spans
+
+    tiles = program_spans.batch_tiles(rec)
+    if tiles is None:
+        return
+    log(f"window batches by tile budget: "
+        f"{dict(sorted(collections.Counter(t for _, t in tiles).items()))}")
+    log("window batches (requests x tiles): "
+        + " ".join(f"{n}x{t}" for n, t in tiles))
+
+
 def main(argv=None, *, root: Path | None = None,
          require_tpu: bool = True, control: bool = False) -> int:
     """One run of one cell. ``root`` (the checkout holding BENCHMARK.json)
@@ -176,84 +275,53 @@ def main(argv=None, *, root: Path | None = None,
             return 2
 
     import adapter
-    import gen
     import loadgen
     import reference
     import tracereduce
 
-    spec = gen.Spec.from_config(cfg)
-    win = cfg["windows"][mix["window"]]
-    serving = cfg["serving"]
-    max_batch = serving["max_batch_size"]
-
-    def phase(label, t0):
-        log(f"set-up {label}: {time.monotonic() - t0:.3f} s")
-
     log(f"device: {devices[0].platform} {devices[0].device_kind} "
         f"x{len(devices)}; cell {args.workload}, seed {args.seed}")
-    t = time.monotonic()
-    lib, table = gen.build_library(spec, args.seed)
-    phase(f"library ({spec.library_rows} targets + as many decoys, "
-          f"{2 * spec.library_rows * spec.dim // 8} packed bytes)", t)
-    t = time.monotonic()
-    pool = gen.query_pool(spec, args.seed, QUERY_TAG,
-                          loadgen.pool_size(mix, args.seconds),
-                          table, max_batch)
-    sizes = loadgen.warm_sizes(mix, max_batch)
-    warm = gen.query_pool(spec, args.seed, WARM_TAG,
-                          loadgen.WARM_SWEEPS * sum(sizes), table, max_batch)
-    del table
-    phase(f"query pool ({len(pool)} spectra, {len(warm)} for warm-up)", t)
-    t = time.monotonic()
-    dep = adapter.Deployment(lib, serving, win)
-    phase("bank and server", t)
-    log(f"device 0 peak memory after the bank build: "
-        f"{(devices[0].memory_stats() or {}).get('peak_bytes_in_use', 0)} "
-        f"bytes")
-    t = time.monotonic()
-    sweeps = loadgen.warm_up(dep, warm, sizes, lambda: compiles[0])
-    phase(f"warm-up ({sweeps} sweeps, {compiles[0]} programs so far)", t)
+    spec, lib, (pool,), dep = bring_up(
+        cfg, mix, args.seed,
+        [(args.seed, loadgen.pool_size(mix, args.seconds))], compiles)
+    win = cfg["windows"][mix["window"]]
+    serving = cfg["serving"]
     setup_s = time.monotonic() - T_START
-    log(f"set-up total: {setup_s:.3f} s")
 
     trace_dir = tempfile.mkdtemp() if args.trace else None
     dep.annotate = adapter.Annotate(bool(args.trace))
-    counters0, compiles0, n_batches0 = (dep.oms_counters(), compiles[0],
-                                        len(dep.batches))
     if trace_dir:
         jax.profiler.start_trace(trace_dir)
-    with dep.annotate("bench.window"):
-        w = loadgen.run(dep, mix, pool, args.seconds, args.seed, max_batch,
-                        dep.annotate)
+    w, rec = measure(dep, mix, pool, args.seconds, args.seed, compiles, cfg)
     if trace_dir:
         jax.profiler.stop_trace()
-    window_programs = compiles[0] - compiles0
-    counters1 = dep.oms_counters()
-    window_batches = [b for b in dep.batches[n_batches0:]
-                      if b.t_dispatch < w.t_end]
+    if w.t0 > w.t_start:
+        # the pre-roll fills the queue before the window: set-up
+        setup_s += w.t0 - w.t_start
+        log(f"set-up pre-roll: {w.t0 - w.t_start:.3f} s")
+    log(f"set-up total: {setup_s:.3f} s")
     loadgen.drain(dep, w)
     stats = devices[0].memory_stats() or {}
     peak = int(stats.get("peak_bytes_in_use", 0))
     log(f"device 0 peak memory: {peak} bytes")
-    log(f"window: {len(w.attempted)} requests due, {len(w.served)} "
-        f"answered, {len(window_batches)} batches dispatched, "
-        f"{window_programs} programs compiled or loaded inside it"
+    failed = sum(1 for r in w.attempted if r not in w.served)
+    log(f"window: {len(w.attempted)} requests due, "
+        f"{len(w.attempted) - failed} answered, "
+        f"{len(rec.window_batches)} batches dispatched, "
+        f"{rec.programs} programs compiled or loaded inside it"
         + (f", closed {w.t_close - w.t0:.3f} s after it opened"
            if w.t_close else ""))
+    log_batches(rec)
     dep.close()
     del dep
     gc.collect()
 
-    rec = types.SimpleNamespace(
-        setup_s=setup_s, t0=w.t0, t_end=w.t_end, t_close=w.t_close,
-        window_s=args.seconds,
-        due=w.due, attempted=w.attempted, served=w.served,
-        window_batches=window_batches,
+    rec.__dict__.update(
+        setup_s=setup_s, t_close=w.t_close, window_s=args.seconds,
         precursor_of={r: float(pool.precursor[i])
                       for r, i in w.pool_row.items()},
-        counters=(counters0, counters1), config=cfg, mix=mix, window=win,
-        peaks=peaks, sorted_prec=np.sort(lib.precursor), trace=None,
-        notes=[])
+        window=win, peaks=peaks, sorted_prec=np.sort(lib.precursor),
+        trace=None, notes=[])
     breakdown = None
     if trace_dir:
         loaded = tracereduce.load(trace_dir)
@@ -277,7 +345,6 @@ def main(argv=None, *, root: Path | None = None,
         log(note)
 
     t = time.monotonic()
-    failed = sum(1 for r in w.attempted if r not in w.served)
     batches = sample_batches(rec, np.random.default_rng(args.seed),
                              mix["check_requests"])
     got = {r: vars(s) for r, s in w.served.items()}
