@@ -19,6 +19,7 @@ import time
 
 import jax
 import numpy as np
+from tracereduce import Annotate
 
 from repro.serve import DBSearchServer, OMSConfig, QueryEncoder, shard_database
 
@@ -46,28 +47,9 @@ class Served:
     has_candidate: bool
 
 
-class Annotate:
-    """A host span that lands in the profiler trace when one is on."""
-
-    def __init__(self, traced: bool):
-        self.traced = traced
-
-    def __call__(self, name: str):
-        if self.traced:
-            return jax.profiler.TraceAnnotation(name)
-        return _Null()
-
-
-class _Null:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
 class Deployment:
     def __init__(self, lib, serving: dict, window: dict, *, fused_e2e=True):
+        self.lib = lib  # on the host, for the reference after close()
         self.db = shard_database(lib.targets, decoys=lib.decoys, fused=True,
                                  precursor=lib.precursor)
         jax.block_until_ready(self.db.data)

@@ -1,7 +1,8 @@
 """Readings for the limits of ``correct``: run a cell on several seeds in
 one process and, beside each run's own comparison with the reference,
-compare the control (the reference on half of every hypervector) with the
-reference on the same served batches.
+compare the control that the cell's kind defines with the reference on
+the same served batches (for ``oms_search``, the reference on half of
+every hypervector; ``bench/kinds/<kind>.py``, ``checks``).
 
     python3 bench/calibrate.py --workload hek293.oms.offline \
         --seeds 1,2,3 --seconds 5

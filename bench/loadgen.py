@@ -40,7 +40,7 @@ class Window:
     t_end: float        # nothing is sent from here on
     due: dict           # rid -> due time (host clock)
     pool_row: dict      # rid -> row of the query pool
-    served: dict        # rid -> adapter.Served
+    served: dict        # rid -> the deployment's answer (Served)
     attempted: list     # rids whose answers are due
     t_close: float = 0.0  # backlog: when the last dispatched answer came
     t_start: float = 0.0  # when the run began: the pre-roll, else t0

@@ -3,17 +3,18 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell names a configuration (``bench/configs/<config>.json``: the
-library, its encoding, the search window and the guarantees) and a
+deployment's sizes, settings and guarantees, and its ``kind``) and a
 traffic mix (``bench/traffic/<mix>.json``), both listed in
-``BENCHMARK.json``. The run makes the library and the queries on the
-device from the seed, brings up the server, warms every program the
-traffic reaches, then measures for ``--seconds``. With ``--trace 1`` the
-window is profiled and the cell's per-layer metrics are read by the
-readers in ``bench/layer_metrics/<metric>.py``; otherwise the end-to-end
-metrics are reported. After the window the served answers of whole
-dispatched batches, drawn from the seed, are compared with the plain
-reference (``bench/reference.py``); every number compared is printed with
-its limit, last on stderr and last in the result line.
+``BENCHMARK.json``. The configuration's kind is a file of its own,
+``bench/kinds/<kind>.py`` (see :func:`load_kind`): it makes the
+deployment's data and inputs from the seed, brings it up and warms every
+program the traffic reaches. This file then measures for ``--seconds``.
+With ``--trace 1`` the window is profiled and the cell's per-layer
+metrics are read by the readers in ``bench/layer_metrics/<metric>.py``;
+otherwise the end-to-end metrics are reported. After the window the
+served answers of whole dispatched batches, drawn from the seed, are
+compared by the kind with its plain reference; every number compared is
+printed with its limit, last on stderr and last in the result line.
 
 It needs a TPU: on any other platform, or with fewer chips than the cell
 asks for, it exits non-zero and prints no result.
@@ -26,7 +27,6 @@ import time
 T_START = time.monotonic()
 
 import argparse  # noqa: E402
-import collections  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -44,7 +44,6 @@ sys.path.insert(0, str(HERE.parent / "src"))
 import numpy as np  # noqa: E402
 import readers  # noqa: E402
 
-QUERY_TAG, WARM_TAG = 10, 11  # generator streams of the seed
 _COMPILES: list[int] = []
 
 
@@ -53,6 +52,8 @@ def log(msg: str) -> None:
 
 
 def load_cell(root: Path, name: str):
+    """The cell, its configuration, traffic mix, end-to-end and per-layer
+    metrics, and its configuration's kind module."""
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -67,7 +68,48 @@ def load_cell(root: Path, name: str):
         return [m for m in metrics if name in m.get("workloads", [name])]
 
     return (cell, cfg, mix, mine(bench["end_to_end"]),
-            mine(bench["per_layer"]))
+            mine(bench["per_layer"]), load_kind(root, config["file"], cfg))
+
+
+def load_kind(root: Path, config_file: str, cfg: dict):
+    """The module ``bench/kinds/<kind>.py`` that the configuration's
+    ``kind`` names: everything of a run that belongs to one kind of
+    deployment. It has these functions:
+
+    * ``bring_up(cfg, mix, seed, pools, compiles, log)``: the data from
+      ``seed``, one input pool for each (pool seed, count) of ``pools``,
+      the deployment and its warm-up; returns (deployment, pools). A pool
+      has ``levels`` and ``precursor`` rows and a length; the deployment
+      serves ``loadgen``: ``submit(levels, precursor)``, ``step``,
+      ``cancel``, ``pending``, ``queued``, ``buckets``, ``batches``,
+      ``annotate``, ``close``.
+    * ``counters(dep)``: the program's counters, read as the window opens
+      and closes (``rec.counters``).
+    * ``describe(rec, dep, pool, w, log)``: the record's fields that only
+      this kind's readers use, and the kind's log of the window.
+    * ``checks(rec, dep, pool, w, batches, control, log)``: the served
+      answers of ``batches`` against the kind's reference (with
+      ``control``, the control's answers in their place); returns
+      ({name: {"value", "limit"}}, requests compared).
+    * optionally ``end_to_end(name, rec)``, for a metric of this kind
+      alone, and ``sweep_fields(rec)``, what ``bench/sweep.py`` reports of
+      a window beside its generic fields.
+
+    No kind, or no such file, stops the run before it starts."""
+    kind = cfg.get("kind")
+    if kind is None:
+        raise SystemExit(f"run.py: {config_file} names no deployment kind "
+                         f"(its \"kind\" picks bench/kinds/<kind>.py)")
+    path = root / "bench" / "kinds" / f"{kind}.py"
+    if not (isinstance(kind, str) and kind.isidentifier()
+            and path.is_file()):
+        raise SystemExit(f"run.py: {config_file} names the kind {kind!r}, "
+                         f"but there is no file bench/kinds/{kind}.py")
+    spec = importlib.util.spec_from_file_location(f"kind_{kind}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # a kind's dataclasses look it up
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(root: Path, metric: str):
@@ -79,21 +121,23 @@ def load_reader(root: Path, metric: str):
     return mod.read
 
 
-def end_to_end(name: str, rec) -> float:
+def end_to_end(name: str, rec, kind) -> float:
     # a metric held to one cell's bound (``spectra_per_s.random``) is
     # computed as the metric its name starts with
-    name = name.partition(".")[0]
-    if name == "setup_s":
+    base = name.partition(".")[0]
+    if base == "setup_s":
         return rec.setup_s
-    if name in ("p50_ms", "p95_ms"):
+    if base in ("p50_ms", "p95_ms"):
         # an answer that never came counts as infinitely late
         lat = [(rec.served[r].t_done - rec.due[r]) * 1e3
                if r in rec.served else math.inf for r in rec.attempted]
-        return readers.percentile(lat, 50 if name == "p50_ms" else 95)
-    if name == "spectra_per_s":
+        return readers.percentile(lat, 50 if base == "p50_ms" else 95)
+    if base == "spectra_per_s":
         # every dispatched spectrum, over the time until the last came back
         done = sum(1 for r in rec.attempted if r in rec.served)
         return done / (rec.t_close - rec.t0)
+    if hasattr(kind, "end_to_end"):
+        return kind.end_to_end(name, rec)
     raise ValueError(f"no end-to-end metric {name!r} in this harness")
 
 
@@ -144,73 +188,19 @@ def compile_cache(root: Path) -> list[int]:
     return _COMPILES
 
 
-def bring_up(cfg: dict, mix: dict, seed: int, pools, compiles):
-    """A cell's set-up: the library from ``seed`` (its precursors from a
-    random order's ``set_seed``), a query pool of ``count`` spectra for
-    each (pool seed, count) of ``pools``, the bank and the server, then the
-    warm-up of every program the mix reaches over those pools' precursors.
-    Returns (spec, library, pools, deployment)."""
-    import jax
-
-    import adapter
-    import gen
-    import loadgen
-
-    spec = gen.Spec.from_config(cfg)
-    serving = cfg["serving"]
-    max_batch = serving["max_batch_size"]
-    device = jax.devices()[0]
-
-    def phase(label, t0):
-        log(f"set-up {label}: {time.monotonic() - t0:.3f} s")
-
-    t = time.monotonic()
-    # a random order's set fixes every precursor: the same work each seed
-    random_order = mix.get("precursor_order") == "random"
-    lib, table = gen.build_library(
-        spec, seed, precursor_seed=mix["set_seed"] if random_order else None)
-    phase(f"library ({spec.library_rows} targets + as many decoys, "
-          f"{2 * spec.library_rows * spec.dim // 8} packed bytes)", t)
-    t = time.monotonic()
-    made = [gen.query_pool(spec, s, QUERY_TAG, count, table, max_batch,
-                           mix.get("precursor_order", "stratified"),
-                           mix.get("set_batches", 0), mix.get("set_seed", 0))
-            for s, count in pools]
-    warm = gen.query_pool(spec, seed, WARM_TAG,
-                          loadgen.warm_pool_size(mix, max_batch), table,
-                          max_batch)
-    del table
-    phase(f"query pool ({sum(map(len, made))} spectra, {len(warm)} for "
-          f"warm-up)", t)
-    t = time.monotonic()
-    dep = adapter.Deployment(lib, serving, cfg["windows"][mix["window"]])
-    phase("bank and server", t)
-    log(f"device 0 peak memory after the bank build: "
-        f"{(device.memory_stats() or {}).get('peak_bytes_in_use', 0)} bytes")
-    t = time.monotonic()
-    batches = loadgen.warm_batches(
-        mix, max_batch, dep.buckets, warm,
-        min(float(p.precursor.min()) for p in made),
-        max(float(p.precursor.max()) for p in made))
-    sweeps = loadgen.warm_up(dep, batches, lambda: compiles[0])
-    phase(f"warm-up ({sweeps} sweeps of {len(batches[0])} batches, "
-          f"{compiles[0]} programs so far)", t)
-    return spec, lib, made, dep
-
-
-def measure(dep, mix: dict, pool, seconds: float, seed: int, compiles,
-            cfg: dict):
+def measure(kind, dep, mix: dict, pool, seconds: float, seed: int,
+            compiles, cfg: dict):
     """One measured window. Returns it and its record: the requests due,
     those answered so far (the answers still in flight are collected by
     ``loadgen.drain`` into the same dict), the batches dispatched inside
-    it, the planner's counters as it opened and closed, and the programs
+    it, the kind's counters as it opened and closed, and the programs
     compiled or loaded inside it."""
     import loadgen
 
     opened = {}
 
     def on_open():
-        opened.update(counters=dep.oms_counters(), compiles=compiles[0],
+        opened.update(counters=kind.counters(dep), compiles=compiles[0],
                       batches=len(dep.batches))
 
     w = loadgen.run(dep, mix, pool, seconds, seed,
@@ -220,31 +210,17 @@ def measure(dep, mix: dict, pool, seconds: float, seed: int, compiles,
         served=w.served,
         window_batches=[b for b in dep.batches[opened["batches"]:]
                         if b.t_dispatch < w.t_end],
-        counters=(opened["counters"], dep.oms_counters()),
+        counters=(opened["counters"], kind.counters(dep)),
         programs=compiles[0] - opened["compiles"], config=cfg, mix=mix)
     return w, rec
-
-
-def log_batches(rec) -> None:
-    """Log the window's batches by OMS tile budget, and each batch's
-    requests and budget in dispatch order."""
-    import program_spans
-
-    tiles = program_spans.batch_tiles(rec)
-    if tiles is None:
-        return
-    log(f"window batches by tile budget: "
-        f"{dict(sorted(collections.Counter(t for _, t in tiles).items()))}")
-    log("window batches (requests x tiles): "
-        + " ".join(f"{n}x{t}" for n, t in tiles))
 
 
 def main(argv=None, *, root: Path | None = None,
          require_tpu: bool = True, control: bool = False) -> int:
     """One run of one cell. ``root`` (the checkout holding BENCHMARK.json)
-    and ``require_tpu`` are for the tests; ``control`` logs the program's
-    comparison, then puts the control's answers in place of the served
-    ones, so that the result line judges the control
+    and ``require_tpu`` are for the tests; ``control`` has the kind log
+    the program's comparison, then put the control's answers in place of
+    the served ones, so that the result line judges the control
     (``bench/calibrate.py``)."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -253,7 +229,7 @@ def main(argv=None, *, root: Path | None = None,
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     root = Path(root or HERE.parent)
-    cell, cfg, mix, e2e, per_layer = load_cell(root, args.workload)
+    cell, cfg, mix, e2e, per_layer, kind = load_cell(root, args.workload)
 
     import jax
     devices = jax.devices()
@@ -274,25 +250,22 @@ def main(argv=None, *, root: Path | None = None,
             print(f"run.py: {e.args[0]}", file=sys.stderr)
             return 2
 
-    import adapter
     import loadgen
-    import reference
     import tracereduce
 
     log(f"device: {devices[0].platform} {devices[0].device_kind} "
         f"x{len(devices)}; cell {args.workload}, seed {args.seed}")
-    spec, lib, (pool,), dep = bring_up(
+    dep, (pool,) = kind.bring_up(
         cfg, mix, args.seed,
-        [(args.seed, loadgen.pool_size(mix, args.seconds))], compiles)
-    win = cfg["windows"][mix["window"]]
-    serving = cfg["serving"]
+        [(args.seed, loadgen.pool_size(mix, args.seconds))], compiles, log)
     setup_s = time.monotonic() - T_START
 
     trace_dir = tempfile.mkdtemp() if args.trace else None
-    dep.annotate = adapter.Annotate(bool(args.trace))
+    dep.annotate = tracereduce.Annotate(bool(args.trace))
     if trace_dir:
         jax.profiler.start_trace(trace_dir)
-    w, rec = measure(dep, mix, pool, args.seconds, args.seed, compiles, cfg)
+    w, rec = measure(kind, dep, mix, pool, args.seconds, args.seed, compiles,
+                     cfg)
     if trace_dir:
         jax.profiler.stop_trace()
     if w.t0 > w.t_start:
@@ -311,17 +284,13 @@ def main(argv=None, *, root: Path | None = None,
         f"{rec.programs} programs compiled or loaded inside it"
         + (f", closed {w.t_close - w.t0:.3f} s after it opened"
            if w.t_close else ""))
-    log_batches(rec)
+    kind.describe(rec, dep, pool, w, log)
     dep.close()
-    del dep
     gc.collect()
 
     rec.__dict__.update(
         setup_s=setup_s, t_close=w.t_close, window_s=args.seconds,
-        precursor_of={r: float(pool.precursor[i])
-                      for r, i in w.pool_row.items()},
-        window=win, peaks=peaks, sorted_prec=np.sort(lib.precursor),
-        trace=None, notes=[])
+        peaks=peaks, trace=None, notes=[])
     breakdown = None
     if trace_dir:
         loaded = tracereduce.load(trace_dir)
@@ -337,55 +306,22 @@ def main(argv=None, *, root: Path | None = None,
     metrics = {}
     for m in (per_layer if args.trace else e2e):
         value = (load_reader(root, m["name"])(rec) if args.trace
-                 else end_to_end(m["name"], rec))
+                 else end_to_end(m["name"], rec, kind))
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
     for note in rec.notes:
         log(note)
 
-    t = time.monotonic()
     batches = sample_batches(rec, np.random.default_rng(args.seed),
                              mix["check_requests"])
-    got = {r: vars(s) for r, s in w.served.items()}
-
-    def query(rid):
-        i = w.pool_row[rid]
-        return pool.levels[i].astype(np.int64), pool.precursor[i]
-
-    def answers(half: bool) -> dict:
-        ref = reference.Reference(lib, tol=win["tol"],
-                                  open_tol=win["open_tol"], k=serving["k"],
-                                  dim=spec.dim, half=half)
-        return reference.answer_batches(ref, batches, query, serving["fdr"])
-
-    want = answers(half=False)
-    found = reference.compare(want, got)
-    log(f"reference: {found['checked_requests']} requests in "
-        f"{len(batches)} batches and {2 * len(lib.checked_rows)} bank rows "
-        f"in {time.monotonic() - t:.3f} s")
-    if control:
-        log(f"program: topk_mismatch {found['topk_mismatch']} fdr_mismatch "
-            f"{found['fdr_mismatch']} over {found['checked_requests']} "
-            f"requests")
-        # the control's answers go where the served ones were
-        got.update(answers(half=True))
-        found = reference.compare(want, got)
-        log(f"control (half the dimensions): topk_mismatch "
-            f"{found['topk_mismatch']} fdr_mismatch {found['fdr_mismatch']} "
-            f"over {found['checked_requests']} requests")
-    checks = {
-        "missing": {"value": failed, "limit": 0},
-        "topk_mismatch": {"value": found["topk_mismatch"], "limit": 0},
-        "fdr_mismatch": {"value": found["fdr_mismatch"], "limit": 0},
-        "bank_rows_mismatch": {"value": reference.check_bank(lib),
-                               "limit": 0},
-    }
+    found, checked = kind.checks(rec, dep, pool, w, batches, control, log)
+    checks = {"missing": {"value": failed, "limit": 0}, **found}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     # too few whole batches to check is not a pass
-    checks["checked_requests"] = {"value": found["checked_requests"],
+    checks["checked_requests"] = {"value": checked,
                                   "limit": mix["check_requests"]}
-    correct &= found["checked_requests"] >= mix["check_requests"]
+    correct &= checked >= mix["check_requests"]
 
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),

@@ -1,9 +1,10 @@
 """The knee sweep of an open-loop cell: one deployment, warmed once, then a
 window at each offered rate, each with a query pool and arrivals of its
 own seed. Prints one JSON line per rate: requests due and answered, p50 and
-p95 latency, the queue's wait, batches, their fill and sizes, the OMS
-over-scan and tile budgets, the requests still queued when the window
-closed, and the programs compiled inside the window.
+p95 latency, the queue's wait, batches, their fill and sizes, the requests
+still queued when the window closed, the programs compiled inside the
+window, and what the cell's kind adds (its optional ``sweep_fields``; for
+``oms_search``, the OMS over-scan and tile budgets).
 
     python3 bench/sweep.py --workload iprg2012.oms.poisson.best_order \
         --seed 1 --seconds 10 --rates 800,840,882
@@ -16,7 +17,6 @@ benchmark's own runs never do this.
 from __future__ import annotations
 
 import argparse
-import collections
 import json
 import sys
 from pathlib import Path
@@ -25,7 +25,6 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(HERE.parent / "src"))
 
-import program_spans  # noqa: E402
 import readers  # noqa: E402
 import run  # noqa: E402
 
@@ -41,7 +40,7 @@ def main(argv=None, *, root: Path | None = None,
     args = ap.parse_args(argv)
     rates = [float(r) for r in args.rates.split(",")]
     root = Path(root or HERE.parent)
-    _, cfg, mix, _, _ = run.load_cell(root, args.workload)
+    _, cfg, mix, _, _, kind = run.load_cell(root, args.workload)
     if mix["arrival"] != "poisson":
         raise SystemExit(f"sweep.py: {args.workload} is not an open loop")
 
@@ -57,28 +56,26 @@ def main(argv=None, *, root: Path | None = None,
     pools = [(args.seed + i, loadgen.pool_size(dict(mix, rate_per_s=r),
                                                args.seconds))
              for i, r in enumerate(rates)]
-    _, _, pools, dep = run.bring_up(cfg, mix, args.seed, pools, compiles)
+    dep, pools = kind.bring_up(cfg, mix, args.seed, pools, compiles, run.log)
     for i, (rate, pool) in enumerate(zip(rates, pools)):
-        w, rec = run.measure(dep, dict(mix, rate_per_s=rate), pool,
+        w, rec = run.measure(kind, dep, dict(mix, rate_per_s=rate), pool,
                              args.seconds, args.seed + i, compiles, cfg)
         queued = dep.pending()
         loadgen.drain(dep, w)
         rec.setup_s = 0.0
         sizes = sorted(len(b.rids) for b in rec.window_batches)
-        tiles = collections.Counter(
-            t for _, t in program_spans.batch_tiles(rec) or [])
         row = {"rate_per_s": rate, "seed": args.seed + i,
                "due": len(w.attempted),
                "answered": sum(r in w.served for r in w.attempted),
-               "p50_ms": run.end_to_end("p50_ms", rec),
-               "p95_ms": run.end_to_end("p95_ms", rec),
+               "p50_ms": run.end_to_end("p50_ms", rec, kind),
+               "p95_ms": run.end_to_end("p95_ms", rec, kind),
                "queue_wait_p95_ms": readers.queue_wait_p95_ms(rec),
                "batches": len(sizes), "batch_fill": readers.batch_fill(rec),
                "smallest_batch": min(sizes, default=0),
                "median_batch": sizes[len(sizes) // 2] if sizes else 0,
-               "tiles": {str(k): v for k, v in sorted(tiles.items())},
-               "oms_overscan": readers.oms_overscan(rec),
                "pending_at_close": queued, "programs_in_window": rec.programs}
+        if hasattr(kind, "sweep_fields"):
+            row.update(kind.sweep_fields(rec))
         print(json.dumps(row), flush=True)
     dep.close()
     return 0

@@ -20,7 +20,8 @@ def root(tmp_path_factory):
     """A checkout whose benchmark is the tiny configuration, its three
     mixes and one extra metric, each in a file of its own."""
     root = tmp_path_factory.mktemp("checkout")
-    shutil.copytree(BENCH / "layer_metrics", root / "bench" / "layer_metrics")
+    for sub in ("layer_metrics", "kinds"):
+        shutil.copytree(BENCH / sub, root / "bench" / sub)
     for sub in ("configs", "traffic", "layer_metrics"):
         shutil.copytree(TINY / sub, root / "bench" / sub, dirs_exist_ok=True)
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())

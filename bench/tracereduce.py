@@ -11,6 +11,7 @@ window with no device op, labelled by the host span it overlaps most.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import glob
 import os
@@ -18,6 +19,20 @@ import os
 WINDOW_SPAN = "bench.window"
 HOST_PREFIX = "bench."
 DEVICE_LINE = "XLA Ops"
+
+
+class Annotate:
+    """A harness host span (``bench.*``) that lands in the profiler trace
+    when one is on; a deployment's ``annotate``."""
+
+    def __init__(self, traced: bool):
+        self.traced = traced
+
+    def __call__(self, name: str):
+        if self.traced:
+            import jax
+            return jax.profiler.TraceAnnotation(name)
+        return contextlib.nullcontext()
 
 
 @dataclasses.dataclass(frozen=True)
