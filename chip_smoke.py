@@ -349,7 +349,8 @@ def masked_oms_oracle(db):
     Bank and bands are arguments, so one compile serves every chunk."""
     def run(data, q, starts, lens):
         plan = OMSPlan(starts=starts, lens=lens, num_tiles=1,
-                       scanned_fraction=1.0, candidate_fraction=0.0)
+                       scanned_fraction=1.0, candidate_fraction=0.0,
+                       budget_fraction=1.0)
         return oms_search_encoded(
             dataclasses.replace(db, data=data, fused=False), q, plan, K)
     return run

@@ -33,6 +33,7 @@ to the whole word axis when the bank is narrower (D <= 4096).
 from __future__ import annotations
 
 import jax
+import numpy as np
 
 # per-op alignment constraints: block name -> required multiple
 ALIGN: dict[str, dict[str, int]] = {
@@ -75,6 +76,26 @@ def word_padding(n_words: int, word_chunk: int) -> int:
     none when the whole word axis fits one chunk (the slice then spans the
     axis), else up to a ``word_chunk`` multiple."""
     return 0 if n_words <= word_chunk else (-n_words) % word_chunk
+
+
+def band_tiles(s, e, *, block_q: int, block_r: int, total_tiles: int,
+               num_tiles: int):
+    """Per-Q-block ``(tile_base, tile_count)`` for the banded kernels.
+
+    ``s``/``e``: (Q,) clipped band rows ``[s, e)``, Q a ``block_q``
+    multiple. A block's base is its lowest start tile, clipped so the
+    static ``num_tiles`` grid stays inside the bank; its count is the
+    tiles from there to its highest end, at least 1 and at most
+    ``num_tiles``. The kernels scan tiles ``[base, base + count)`` and
+    skip the grid steps past the count. Array methods only: the kernel
+    wrappers pass jax arrays, the OMS planner numpy ones, so the host
+    counts the tiles the kernels visit.
+    """
+    tb = (s.reshape(-1, block_q).min(axis=1) // block_r).clip(
+        0, total_tiles - num_tiles)
+    last = -(-e.reshape(-1, block_q).max(axis=1) // block_r)
+    tc = (last - tb).clip(1, num_tiles)
+    return tb.astype(np.int32), tc.astype(np.int32)
 
 
 def validate_block(op: str, name: str, value) -> int:

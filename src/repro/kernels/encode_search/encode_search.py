@@ -37,9 +37,10 @@ accumulate to 0 -> sign -1 -> packed bit 0, and padded reference
 words/columns are zero, so cross terms vanish (see ops.py).
 
 The banded variant mirrors ``_topk_banded_kernel``: a scalar-prefetched
-per-Q-block tile base steers the R BlockSpec so only the tiles covering
-each query's OMS precursor window are fetched, with per-query
-``[start, end)`` bounds masking in-tile rows.
+per-Q-block tile base and tile count steer the R BlockSpec so only the
+tiles covering each query's OMS precursor window are fetched, with
+per-query ``[start, end)`` bounds masking in-tile rows; grid steps past a
+block's count are skipped.
 """
 
 from __future__ import annotations
@@ -237,16 +238,17 @@ def encode_search_pallas_call(
     )(num_valid, levels, id_hvs, level_hvs, r)
 
 
-def _encode_search_banded_kernel(tb_ref, levels_ref, id_ref, lv_ref, r_ref,
-                                 starts_ref, ends_ref, ovals_ref, oidx_ref,
-                                 acc_ref, qenc_ref, svals_ref, sidx_ref,
-                                 pc_ref, *, dim: int, k: int, block_r: int,
-                                 word_chunk: int, packed: bool,
+def _encode_search_banded_kernel(tb_ref, tc_ref, levels_ref, id_ref, lv_ref,
+                                 r_ref, starts_ref, ends_ref, ovals_ref,
+                                 oidx_ref, acc_ref, qenc_ref, svals_ref,
+                                 sidx_ref, pc_ref, *, dim: int, k: int,
+                                 block_r: int, word_chunk: int, packed: bool,
                                  r_padded: int, num_levels: int, n_f: int):
-    """Banded twin: after the encode steps only ``num_tiles`` R tiles per
-    Q block are visited, starting at the scalar-prefetched ``tb_ref[i]``
-    (OMS precursor windows), with per-query ``[start, end)`` row bounds —
-    the same contract as ``topk_hamming._topk_banded_kernel``."""
+    """Banded twin: after the encode steps only ``tc_ref[i]`` (at most
+    ``num_tiles``) R tiles per Q block are visited, starting at the
+    scalar-prefetched ``tb_ref[i]`` (OMS precursor windows), with
+    per-query ``[start, end)`` row bounds — the same contract as
+    ``topk_hamming._topk_banded_kernel``."""
     i = pl.program_id(0)
     j = pl.program_id(1)
     bq = levels_ref.shape[0]
@@ -260,7 +262,7 @@ def _encode_search_banded_kernel(tb_ref, levels_ref, id_ref, lv_ref, r_ref,
     _encode_step(j, n_f, levels_ref, id_ref, lv_ref, acc_ref, qenc_ref,
                  num_levels=num_levels, packed=packed)
 
-    @pl.when(j >= n_f)
+    @pl.when((j >= n_f) & (j - n_f < tc_ref[i]))
     def _():
         _search_step(tb_ref[i] + j - n_f, qenc_ref, r_ref, pc_ref, svals_ref,
                      sidx_ref,
@@ -281,6 +283,7 @@ def encode_search_banded_pallas_call(
     level_hvs: jax.Array,  # (m, D) float32 bipolar
     r: jax.Array,          # (R, W) uint32 packed, or (R, D) int8
     tile_base: jax.Array,  # (Q // block_q,) int32 first R tile per Q block
+    tile_count: jax.Array,  # (Q // block_q,) int32 tiles to scan, 1..num_tiles
     starts: jax.Array,     # (Q, 1) int32 per-query band start row
     ends: jax.Array,       # (Q, 1) int32 per-query band end row (exclusive)
     *,
@@ -295,7 +298,7 @@ def encode_search_banded_pallas_call(
 ) -> tuple[jax.Array, jax.Array]:
     """Banded fused encode->search: grid (Q blocks, F / block_f +
     num_tiles), scanning only tiles ``[tile_base[i], tile_base[i] +
-    num_tiles)`` per Q block. Caller contract matches
+    tile_count[i])`` per Q block. Caller contract matches
     ``topk_hamming_banded_pallas_call``."""
     Q, F = levels.shape
     m, D = level_hvs.shape
@@ -313,24 +316,27 @@ def encode_search_banded_pallas_call(
         _encode_search_banded_kernel, dim=dim, k=k, block_r=block_r,
         word_chunk=word_chunk, packed=packed, r_padded=n_r * block_r,
         num_levels=m, n_f=n_f)
+
+    def r_tile(i, j, tb, tc):
+        # steps past the block's count keep its last tile: no new DMA
+        return tb[i] + jnp.minimum(jnp.maximum(j - n_f, 0), tc[i] - 1), 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(Q // block_q, n_f + num_tiles),
         in_specs=[
             pl.BlockSpec((block_q, block_f),
-                         lambda i, j, tb: (i, jnp.minimum(j, last_f))),
+                         lambda i, j, tb, tc: (i, jnp.minimum(j, last_f))),
             pl.BlockSpec((block_f, D),
-                         lambda i, j, tb: (jnp.minimum(j, last_f), 0)),
-            pl.BlockSpec((m, D), lambda i, j, tb: (0, 0)),
-            pl.BlockSpec((block_r, W),
-                         lambda i, j, tb: (tb[i] + jnp.maximum(j - n_f, 0),
-                                           0)),
-            pl.BlockSpec((block_q, 1), lambda i, j, tb: (i, 0)),
-            pl.BlockSpec((block_q, 1), lambda i, j, tb: (i, 0)),
+                         lambda i, j, tb, tc: (jnp.minimum(j, last_f), 0)),
+            pl.BlockSpec((m, D), lambda i, j, tb, tc: (0, 0)),
+            pl.BlockSpec((block_r, W), r_tile),
+            pl.BlockSpec((block_q, 1), lambda i, j, tb, tc: (i, 0)),
+            pl.BlockSpec((block_q, 1), lambda i, j, tb, tc: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_q, k), lambda i, j, tb: (i, 0)),
-            pl.BlockSpec((block_q, k), lambda i, j, tb: (i, 0)),
+            pl.BlockSpec((block_q, k), lambda i, j, tb, tc: (i, 0)),
+            pl.BlockSpec((block_q, k), lambda i, j, tb, tc: (i, 0)),
         ],
         scratch_shapes=_scratch(block_q, block_r, k, D, W, packed),
     )
@@ -343,4 +349,4 @@ def encode_search_banded_pallas_call(
         ],
         interpret=interpret,
         name="encode_search_banded",
-    )(tile_base, levels, id_hvs, level_hvs, r, starts, ends)
+    )(tile_base, tile_count, levels, id_hvs, level_hvs, r, starts, ends)

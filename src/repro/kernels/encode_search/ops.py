@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.block_utils import (
+    band_tiles,
     default_interpret,
     resolve_blocks,
     round_up,
@@ -181,9 +182,10 @@ def encode_search_banded_pallas(
 ) -> tuple[jax.Array, jax.Array]:
     """Banded fused encode->search: each raw spectrum scores only bank
     rows in its own ``[starts[q], starts[q] + lens[q])`` band (an OMS
-    precursor window over a precursor-sorted bank), scanning only
-    ``num_tiles`` R tiles per Q block. Same contract — tile budget,
-    clipping, overflow canonicalization — as
+    precursor window over a precursor-sorted bank), scanning at most
+    ``num_tiles`` R tiles per Q block and only as many as the block's
+    bands span (:func:`repro.kernels.block_utils.band_tiles`). Same
+    contract — tile budget, clipping, overflow canonicalization — as
     ``topk_hamming_banded_pallas``, with the encode fused in. Blocks
     resolve under the op key ``encode_search_banded``.
     """
@@ -250,12 +252,12 @@ def _encode_search_banded_jit(
 
     total_tiles = -(-R // br)
     nt = total_tiles if num_tiles is None else min(num_tiles, total_tiles)
-    tb = jnp.min(s.reshape(-1, bq) // br, axis=1)
-    tb = jnp.clip(tb, 0, total_tiles - nt).astype(jnp.int32)
+    tb, tc = band_tiles(s, e, block_q=bq, block_r=br,
+                        total_tiles=total_tiles, num_tiles=nt)
 
     vals, idx = encode_search_banded_pallas_call(
-        levels, id_hvs, level_hvs, r, tb, s[:, None], e[:, None], dim=dim,
-        k=k, num_tiles=nt, block_q=bq, block_r=br, block_f=bf,
+        levels, id_hvs, level_hvs, r, tb, tc, s[:, None], e[:, None],
+        dim=dim, k=k, num_tiles=nt, block_q=bq, block_r=br, block_f=bf,
         word_chunk=word_chunk, interpret=interpret)
     idx, vals = idx[:Q], vals[:Q]
     if canonicalize:

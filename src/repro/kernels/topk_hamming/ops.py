@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.block_utils import (
+    band_tiles,
     default_interpret,
     resolve_blocks,
     round_up,
@@ -191,13 +192,15 @@ def topk_hamming_banded_pallas(
     Bit-identical to sentinel-masking the full (Q, R) score matrix outside
     the band (and at or past ``num_valid``) and running ``lax.top_k`` — tie
     order and, with ``canonicalize=True``, overflow slots included — but
-    only ``num_tiles`` R tiles per Q block are fetched and scored.
+    each Q block fetches and scores only the tiles its bands span, at most
+    ``num_tiles`` (:func:`repro.kernels.block_utils.band_tiles`).
 
-    num_tiles: static per-Q-block tile budget. Every query's (clipped) band
-      in a Q block must fit in ``num_tiles * block_r`` rows starting at the
-      block's lowest band start — callers compute it host-side from the
-      batch's windows (``repro.serve.oms.plan_candidates``). ``None`` scans
-      the full bank (always correct, no work saved).
+    num_tiles: static per-Q-block tile budget, the kernel grid's width.
+      Every query's (clipped) band in a Q block must fit in ``num_tiles *
+      block_r`` rows starting at the block's lowest band start — callers
+      compute it host-side from the batch's windows
+      (``repro.serve.oms.plan_candidates``). ``None`` sets the full bank
+      as the budget (always correct).
     canonicalize: rewrite sentinel overflow slots (band narrower than k) to
       the oracle's ascending masked indices. Per-shard callers that merge
       and canonicalize globally switch this off.
@@ -252,11 +255,11 @@ def _topk_hamming_banded_jit(
 
     total_tiles = -(-R // br)
     nt = total_tiles if num_tiles is None else min(num_tiles, total_tiles)
-    tb = jnp.min(s.reshape(-1, bq) // br, axis=1)
-    tb = jnp.clip(tb, 0, total_tiles - nt).astype(jnp.int32)
+    tb, tc = band_tiles(s, e, block_q=bq, block_r=br,
+                        total_tiles=total_tiles, num_tiles=nt)
 
     vals, idx = topk_hamming_banded_pallas_call(
-        q, r, tb, s[:, None], e[:, None], dim=dim, k=k, num_tiles=nt,
+        q, r, tb, tc, s[:, None], e[:, None], dim=dim, k=k, num_tiles=nt,
         block_q=bq, block_r=br, word_chunk=word_chunk, interpret=interpret)
     idx, vals = idx[:Q], vals[:Q]
     if canonicalize:

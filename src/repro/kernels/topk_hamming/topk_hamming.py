@@ -156,12 +156,13 @@ def topk_hamming_pallas_call(
     )(num_valid, q, r)
 
 
-def _topk_banded_kernel(tb_ref, q_ref, r_ref, starts_ref, ends_ref,
+def _topk_banded_kernel(tb_ref, tc_ref, q_ref, r_ref, starts_ref, ends_ref,
                         ovals_ref, oidx_ref, svals_ref, sidx_ref, pc_ref, *,
                         dim: int, k: int, block_r: int, word_chunk: int,
                         packed: bool, r_padded: int):
-    """Banded variant: only ``num_tiles`` R tiles per Q block are visited,
-    starting at the scalar-prefetched ``tb_ref[i]`` (OMS precursor windows).
+    """Banded variant: only ``tc_ref[i]`` (at most ``num_tiles``) R tiles
+    per Q block are visited, starting at the scalar-prefetched ``tb_ref[i]``
+    (OMS precursor windows).
 
     ``tb_ref`` generalizes the full kernel's traced ``num_valid`` scalar:
     instead of one mask bound for the whole grid, each Q block gets a tile
@@ -169,7 +170,10 @@ def _topk_banded_kernel(tb_ref, q_ref, r_ref, starts_ref, ends_ref,
     tiles are never even fetched) and each query row gets its own
     ``[start, end)`` bounds. Columns outside the band mask to the sentinel
     exactly like ``num_valid`` padding — the merge is unchanged, so the
-    result is bit-identical to masking the full score matrix.
+    result is bit-identical to masking the full score matrix. Grid steps
+    past the block's tile count ``tc_ref[i]`` are skipped: those tiles hold
+    no row of any band in the block, so they could only have added masked
+    fillers, which overflow canonicalization rewrites.
     """
     i = pl.program_id(0)
     j = pl.program_id(1)
@@ -182,29 +186,32 @@ def _topk_banded_kernel(tb_ref, q_ref, r_ref, starts_ref, ends_ref,
         sidx_ref[...] = r_padded + jax.lax.broadcasted_iota(
             jnp.int32, (bq, k), 1)
 
-    scores = _tile_scores(q_ref, r_ref, pc_ref, dim=dim,
-                          word_chunk=word_chunk, packed=packed)
-
-    tile = tb_ref[i] + j
-    col = tile * block_r + jax.lax.broadcasted_iota(jnp.int32, (bq, br), 1)
-    in_band = (col >= starts_ref[...]) & (col < ends_ref[...])
-    scores = jnp.where(in_band, scores, _SENTINEL)
-    svals, sidx = _select_topk(
-        jnp.concatenate([svals_ref[...], scores], axis=1),
-        jnp.concatenate([sidx_ref[...], col], axis=1), k)
-    svals_ref[...] = svals
-    sidx_ref[...] = sidx
+    @pl.when(j < tc_ref[i])
+    def _():
+        scores = _tile_scores(q_ref, r_ref, pc_ref, dim=dim,
+                              word_chunk=word_chunk, packed=packed)
+        tile = tb_ref[i] + j
+        col = tile * block_r + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, br), 1)
+        in_band = (col >= starts_ref[...]) & (col < ends_ref[...])
+        scores = jnp.where(in_band, scores, _SENTINEL)
+        svals, sidx = _select_topk(
+            jnp.concatenate([svals_ref[...], scores], axis=1),
+            jnp.concatenate([sidx_ref[...], col], axis=1), k)
+        svals_ref[...] = svals
+        sidx_ref[...] = sidx
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
-        ovals_ref[...] = svals
-        oidx_ref[...] = sidx
+        ovals_ref[...] = svals_ref[...]
+        oidx_ref[...] = sidx_ref[...]
 
 
 def topk_hamming_banded_pallas_call(
     q: jax.Array,          # (Q, W) uint32 packed, or (Q, D) int8
     r: jax.Array,          # (R, W) uint32 packed, or (R, D) int8
     tile_base: jax.Array,  # (Q // block_q,) int32 first R tile per Q block
+    tile_count: jax.Array,  # (Q // block_q,) int32 tiles to scan, 1..num_tiles
     starts: jax.Array,     # (Q, 1) int32 per-query band start row
     ends: jax.Array,       # (Q, 1) int32 per-query band end row (exclusive)
     *,
@@ -217,13 +224,15 @@ def topk_hamming_banded_pallas_call(
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Banded streaming top-k: grid (Q blocks, num_tiles), scanning only
-    tiles ``[tile_base[i], tile_base[i] + num_tiles)`` per Q block.
+    tiles ``[tile_base[i], tile_base[i] + tile_count[i])`` per Q block.
 
     Caller contract: for every Q block i, every query's ``[start, end)``
     must lie inside the scanned rows
-    ``[tile_base[i] * block_r, (tile_base[i] + num_tiles) * block_r)``
-    and ``tile_base[i] + num_tiles <= cdiv(R, block_r)`` — band rows outside
-    the scanned window would be silently skipped.
+    ``[tile_base[i] * block_r, (tile_base[i] + tile_count[i]) * block_r)``,
+    ``1 <= tile_count[i] <= num_tiles`` and ``tile_base[i] + num_tiles <=
+    cdiv(R, block_r)`` — band rows outside the scanned window would be
+    silently skipped. :func:`repro.kernels.block_utils.band_tiles` computes
+    both arrays.
     """
     Q, W = q.shape
     R = r.shape[0]
@@ -236,18 +245,23 @@ def topk_hamming_banded_pallas_call(
     kernel = functools.partial(
         _topk_banded_kernel, dim=dim, k=k, block_r=block_r,
         word_chunk=word_chunk, packed=packed, r_padded=n_r * block_r)
+
+    def r_tile(i, j, tb, tc):
+        # steps past the block's count keep its last tile: no new DMA
+        return tb[i] + jnp.minimum(j, tc[i] - 1), 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(Q // block_q, num_tiles),
         in_specs=[
-            pl.BlockSpec((block_q, W), lambda i, j, tb: (i, 0)),
-            pl.BlockSpec((block_r, W), lambda i, j, tb: (tb[i] + j, 0)),
-            pl.BlockSpec((block_q, 1), lambda i, j, tb: (i, 0)),
-            pl.BlockSpec((block_q, 1), lambda i, j, tb: (i, 0)),
+            pl.BlockSpec((block_q, W), lambda i, j, tb, tc: (i, 0)),
+            pl.BlockSpec((block_r, W), r_tile),
+            pl.BlockSpec((block_q, 1), lambda i, j, tb, tc: (i, 0)),
+            pl.BlockSpec((block_q, 1), lambda i, j, tb, tc: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_q, k), lambda i, j, tb: (i, 0)),
-            pl.BlockSpec((block_q, k), lambda i, j, tb: (i, 0)),
+            pl.BlockSpec((block_q, k), lambda i, j, tb, tc: (i, 0)),
+            pl.BlockSpec((block_q, k), lambda i, j, tb, tc: (i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, k), jnp.int32),
@@ -264,4 +278,4 @@ def topk_hamming_banded_pallas_call(
         ],
         interpret=interpret,
         name="topk_hamming_banded",
-    )(tile_base, q, r, starts, ends)
+    )(tile_base, tile_count, q, r, starts, ends)

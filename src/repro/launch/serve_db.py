@@ -337,8 +337,8 @@ def main(argv=None):
     if o is not None:
         print(f"oms: window (-{o['tol']:g}, +{o['open_tol']:g}), candidate "
               f"fraction {o['candidate_fraction']:.3f}, scanned fraction "
-              f"{o['scanned_fraction']:.3f}, {o['no_candidate']} queries "
-              f"with empty windows")
+              f"{o['scanned_fraction']:.3f} of a {o['budget_fraction']:.3f} "
+              f"budget, {o['no_candidate']} queries with empty windows")
     print(f"identified at {args.fdr:.0%} FDR: {accepted}/{total} "
           f"({correct} correct identity)")
     return s

@@ -197,8 +197,9 @@ def _local_oms_topk(q_enc, refs_local, base, k: int, num_rows: int, dim: int,
 def _local_oms_topk_fused(q_enc, refs_local, base, k: int, num_rows: int,
                           dim: int, starts, ends, num_tiles: int):
     """Banded-kernel twin of ``_local_oms_topk``: one kernel launch per
-    band (decoy block, target block), each scanning only ``num_tiles`` R
-    tiles around that band, then a local merge over the 2k candidates.
+    band (decoy block, target block), each Q block scanning only the R
+    tiles its windows span in that band (at most ``num_tiles``), then a
+    local merge over the 2k candidates.
 
     Band blocks concatenate in ascending global-row order (decoy rows
     precede target rows in the sorted layout) so the merge's positional
@@ -1305,6 +1306,7 @@ class SearchExecutor:
         srv._oms_single_launch += single
         srv._oms_cand_frac += plan.candidate_fraction
         srv._oms_scan_frac += plan.scanned_fraction
+        srv._oms_budget_frac += plan.budget_fraction
         srv._oms_no_candidate += int((~valid).sum())
         return BatchHandle(reqs=reqs, tenant=tenant, db=db, n=n, idx=idx,
                            vals=vals, valid=valid,
@@ -1510,6 +1512,7 @@ class DBSearchServer:
         self._oms_single_launch = 0
         self._oms_cand_frac = 0.0
         self._oms_scan_frac = 0.0
+        self._oms_budget_frac = 0.0
         self._oms_no_candidate = 0
         self.encoder = encoder
         self.fused_e2e = bool(fused_e2e)
@@ -1750,6 +1753,7 @@ class DBSearchServer:
                 "single_launch_batches": self._oms_single_launch,
                 "candidate_fraction": self._oms_cand_frac / nb,
                 "scanned_fraction": self._oms_scan_frac / nb,
+                "budget_fraction": self._oms_budget_frac / nb,
                 "no_candidate": self._oms_no_candidate,
             }
         else:

@@ -317,6 +317,7 @@ class MergedOMSPlan:
     lens: np.ndarray         # (B, Q) int32
     candidate_fraction: float
     scanned_fraction: float
+    budget_fraction: float
 
     @property
     def has_candidate(self) -> np.ndarray:
@@ -339,10 +340,15 @@ def merged_oms_plan(base, delta: DeltaBank, query_prec: np.ndarray,
     cand = float(lens.sum()) / max(q * max(layout.num_rows, 1), 1)
     base_padded = base.num_shards * base.shard_rows
     total = max(base_padded + delta.db.num_rows, 1)
-    scanned = min(1.0, (bplan.scanned_fraction * base_padded
-                        + delta.db.num_rows) / total)
+
+    def with_delta(base_fraction: float) -> float:
+        return min(1.0, (base_fraction * base_padded
+                         + delta.db.num_rows) / total)
+
     return MergedOMSPlan(base=bplan, delta=dplan, starts=starts, lens=lens,
-                         candidate_fraction=cand, scanned_fraction=scanned)
+                         candidate_fraction=cand,
+                         scanned_fraction=with_delta(bplan.scanned_fraction),
+                         budget_fraction=with_delta(bplan.budget_fraction))
 
 
 def merged_oms_search_encoded(base, delta: DeltaBank, q_enc, q_raw,

@@ -33,6 +33,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.kernels.block_utils import band_tiles
+
 
 @dataclasses.dataclass(frozen=True)
 class OMSConfig:
@@ -138,10 +140,14 @@ class OMSPlan:
     the banded kernel needs.
 
     ``num_tiles`` is bucketed to a power of two so repeated batches with
-    similar window spans reuse the same jit signature; ``scanned_fraction``
+    similar window spans reuse the same jit signature. Within that grid
+    each (band, query block) scans only the tiles its windows span
+    (:func:`repro.kernels.block_utils.band_tiles`). ``scanned_fraction``
     is the work the banded kernel actually does relative to a full scan
-    (tiles scanned per query block / total tiles), ``candidate_fraction``
-    the selectivity of the window itself (candidate rows / bank rows).
+    (tiles visited per query block, summed over bands, / total tiles),
+    ``budget_fraction`` the same for the static grid (``num_tiles`` per
+    band and block), and ``candidate_fraction`` the selectivity of the
+    window itself (candidate rows / bank rows).
     """
 
     starts: np.ndarray       # (B, Q) int32, sorted-layout rows
@@ -149,6 +155,7 @@ class OMSPlan:
     num_tiles: int           # static per-band tile count for the kernel
     scanned_fraction: float
     candidate_fraction: float
+    budget_fraction: float
 
     @property
     def has_candidate(self) -> np.ndarray:
@@ -177,26 +184,32 @@ def plan_candidates(index: PrecursorIndex, query_prec: np.ndarray,
     kernel wrapper will choose. The returned ``num_tiles`` covers, for every
     band and every query block, the tiles from ``floor(min start / block_r)``
     to ``ceil(max end / block_r)`` — the invariant the banded kernel's
-    tile-base prefetch relies on.
+    tile-base prefetch relies on. ``scanned_fraction`` counts the tiles the
+    kernel visits: per (band, block), from the block's clipped tile base to
+    its highest end, as the kernel wrapper computes them on the device.
     """
     starts, lens = index.candidate_ranges(query_prec, cfg)
-    q = starts.shape[1]
+    num_bands, q = starts.shape
     nr = index.num_rows
     total_tiles = max(1, _round_up(max(num_rows_padded, 1), block_r) // block_r)
     bq = min(block_q, _round_up(max(q, 1), 8))
-    span = 1
-    for b in range(starts.shape[0]):
-        s = starts[b]
-        e = s + lens[b]
-        for i in range(0, q, bq):
-            blk_s = int(s[i:i + bq].min()) // block_r
-            blk_e = -(-int(e[i:i + bq].max()) // block_r)
-            span = max(span, blk_e - blk_s)
+    # edge-pad to whole query blocks, as the kernel wrappers do
+    pad = ((0, 0), (0, (-q) % bq))
+    s = np.pad(starts, pad, mode="edge")
+    e = np.pad(starts + lens, pad, mode="edge")
+    lo = s.reshape(num_bands, -1, bq).min(axis=2) // block_r
+    hi = -(-e.reshape(num_bands, -1, bq).max(axis=2) // block_r)
+    span = int((hi - lo).max(initial=1))
     num_tiles = min(_next_pow2(span), total_tiles)
+    _, counts = band_tiles(s.ravel(), e.ravel(), block_q=bq, block_r=block_r,
+                           total_tiles=total_tiles, num_tiles=num_tiles)
+    n_blocks = max(s.shape[1] // bq, 1)
     cand = float(lens.sum()) / max(q * max(nr, 1), 1)
-    scanned = min(1.0, starts.shape[0] * num_tiles / total_tiles)
+    budget = min(1.0, num_bands * num_tiles / total_tiles)
+    scanned = min(1.0, int(counts.sum()) / (n_blocks * total_tiles))
     return OMSPlan(starts=starts, lens=lens, num_tiles=num_tiles,
-                   scanned_fraction=scanned, candidate_fraction=cand)
+                   scanned_fraction=scanned, candidate_fraction=cand,
+                   budget_fraction=budget)
 
 
 def translate_indices(index: PrecursorIndex, idx: np.ndarray) -> np.ndarray:

@@ -156,6 +156,32 @@ class TestFusedVsStagedOracle:
         _assert_same(got, want, q, r, d, k)
 
 
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "int8"])
+def test_banded_per_block_tile_counts(mixed_span_bands, packed):
+    """The fused banded kernel on Q blocks that need 1 to 8 tiles of an
+    8-tile budget (a narrow block, one as wide as the budget, a tile base
+    clipped at the bank's end, an empty window, an edge-padded tail):
+    each scans only its own count, bit-identical to the staged oracle."""
+    starts, lens, rows, budget = mixed_span_bands
+    rng = np.random.default_rng(31 + packed)
+    f, m = 8, 4
+    d = 64 if packed else 55
+    id_hvs, level_hvs = _codebooks(rng, f, d, m)
+    levels = _levels(rng, starts.shape[0], f, m)
+    if packed:
+        bank = jnp.asarray(
+            rng.integers(0, 2**32, (rows, d // 32), dtype=np.uint32))
+    else:
+        bank = jnp.asarray(rng.choice([-1, 1], size=(rows, d)).astype(np.int8))
+    got = encode_search_banded_pallas(
+        levels, id_hvs, level_hvs, bank, jnp.asarray(starts),
+        jnp.asarray(lens), dim=d, k=5, num_tiles=budget, block_q=8,
+        block_r=128)
+    want = encode_search_banded_ref(levels, id_hvs, level_hvs, bank,
+                                    starts, lens, k=5)
+    _assert_same(got, want, packed)
+
+
 # --------------------------------------------------------------------------
 # routed configurations: fused e2e == staged e2e through the serve layer
 # --------------------------------------------------------------------------

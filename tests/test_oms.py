@@ -10,9 +10,11 @@ covered in tests/test_serve.py; the real 8-device mesh in its slow tier.
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core.hd.similarity import bitpack_bipolar
+from repro.kernels.block_utils import band_tiles
 from repro.kernels.topk_hamming import (
     canonicalize_overflow_slots,
     topk_hamming_banded_pallas,
@@ -56,6 +58,44 @@ def _random_bands(rng, q, r, *, allow_empty=True):
 # --------------------------------------------------------------------------
 # banded kernel vs the sentinel-masked full-matrix oracle
 # --------------------------------------------------------------------------
+
+def test_band_tiles_per_block(mixed_span_bands):
+    """Each Q block of the mixed batch gets its own tile base and count:
+    1 tile for a window ending on a tile edge, the whole budget for the
+    widest block, a base clipped at the bank's end, and the edge-padded
+    tail block as narrow as its real queries."""
+    starts, lens, rows, budget = mixed_span_bands
+    s = np.pad(starts, (0, 5), mode="edge")
+    e = np.pad(starts + lens, (0, 5), mode="edge")
+    tb, tc = band_tiles(jnp.asarray(s), jnp.asarray(e), block_q=8,
+                        block_r=128, total_tiles=-(-rows // 128),
+                        num_tiles=budget)
+    np.testing.assert_array_equal(np.asarray(tb), [1, 2, 5, 4, 2])
+    np.testing.assert_array_equal(np.asarray(tc), [1, 8, 8, 3, 2])
+
+
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "int8"])
+def test_banded_per_block_tile_counts(mixed_span_bands, packed):
+    """Q blocks that need 1 to 8 tiles of an 8-tile budget each scan only
+    their own count; results stay bit-identical to the masked-full-matrix
+    oracle after overflow canonicalization."""
+    starts, lens, rows, budget = mixed_span_bands
+    rng = np.random.default_rng(29 + packed)
+    q = starts.shape[0]
+    if packed:
+        dim = 64
+        qs = jnp.asarray(rng.integers(0, 2**32, (q, 2), dtype=np.uint32))
+        rs = jnp.asarray(rng.integers(0, 2**32, (rows, 2), dtype=np.uint32))
+    else:
+        dim = 40
+        qs, rs = _bipolar(rng, (q, dim)), _bipolar(rng, (rows, dim))
+    starts, lens = jnp.asarray(starts), jnp.asarray(lens)
+    got = topk_hamming_banded_pallas(qs, rs, starts, lens, dim=dim, k=5,
+                                     num_tiles=budget, block_q=8,
+                                     block_r=128)
+    want = topk_hamming_banded_ref(qs, rs, starts, lens, dim, 5)
+    _assert_same(got, want, packed)
+
 
 class TestBandedVsOracleProperties:
     @settings(max_examples=10)
@@ -289,6 +329,39 @@ class TestOMSPlan:
                               block_q=8)
         assert srt.num_tiles <= unsorted.num_tiles
         assert srt.scanned_fraction < 1.0
+
+    def test_scanned_fraction_counts_visited_tiles(self):
+        """``scanned_fraction`` is the sum of the per-(band, block) tile
+        counts the kernel visits (the device's ``band_tiles`` on the same
+        windows), over blocks x bank tiles; ``budget_fraction`` and
+        ``num_tiles`` stay the static grid's, so no new program compiles."""
+        rng = np.random.default_rng(19)
+        prec = rng.uniform(400, 1600, 6000).astype(np.float32)
+        index = build_precursor_index(prec, prec)  # decoy and target bands
+        qp = np.sort(rng.uniform(400, 1600, 61)).astype(np.float32)
+        rows_padded, bq, br = 12032, 8, 128
+        plan = plan_candidates(index, qp, OMSConfig(tol=2.5, open_tol=27.5),
+                               num_rows_padded=rows_padded, block_q=bq)
+
+        total = rows_padded // br
+        pad = ((0, 0), (0, (-qp.shape[0]) % bq))
+        s = np.pad(plan.starts, pad, mode="edge")
+        e = np.pad(plan.starts + plan.lens, pad, mode="edge")
+        lo = s.reshape(2, -1, bq).min(axis=2) // br
+        hi = -(-e.reshape(2, -1, bq).max(axis=2) // br)
+        span = int((hi - lo).max())
+        nt = min(1 << (span - 1).bit_length(), total)
+        assert plan.num_tiles == nt
+        counts = np.clip(hi - np.clip(lo, 0, total - nt), 1, nt)
+        for b in range(2):
+            _, tc = band_tiles(jnp.asarray(s[b]), jnp.asarray(e[b]),
+                               block_q=bq, block_r=br, total_tiles=total,
+                               num_tiles=nt)
+            np.testing.assert_array_equal(np.asarray(tc), counts[b])
+        n_blocks = lo.shape[1]
+        assert plan.scanned_fraction == counts.sum() / (n_blocks * total)
+        assert plan.budget_fraction == min(1.0, 2 * nt / total)
+        assert plan.scanned_fraction < plan.budget_fraction
 
     def test_has_candidate_flags_empty_windows(self):
         prec = np.asarray([500.0, 510.0], np.float32)

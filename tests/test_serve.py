@@ -289,6 +289,7 @@ def test_oms_server_matches_direct_search():
     oms_stats = srv.summary()["oms"]
     assert oms_stats["batches"] == 1
     assert 0.0 < oms_stats["candidate_fraction"] < 1.0
+    assert 0.0 < oms_stats["scanned_fraction"] <= oms_stats["budget_fraction"]
 
 
 def test_oms_server_ragged_flush_padding_is_invisible():
